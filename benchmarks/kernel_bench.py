@@ -45,6 +45,7 @@ TRAJECTORY_PATH = os.path.join(
 from repro.core import AnalogConfig, analog_dot
 from repro.core.redundant import time_averaged_dot_explicit
 from repro.kernels import analog_matmul
+from repro.runtime.compile_cache import enable_compile_cache
 
 SHAPES = [(256, 256, 256), (512, 512, 512), (384, 640, 512)]
 K_REPEATS = [1, 4, 16]
@@ -177,6 +178,7 @@ def _write_trajectory(out, smoke: bool) -> str:
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--smoke", action="store_true", help="tiny sweep for CI")
     ap.add_argument("--force", action="store_true", help="ignore cached JSON")

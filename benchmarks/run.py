@@ -22,6 +22,9 @@ def main() -> None:
     ap.add_argument("--only", default=None, help="comma-separated benchmark names")
     args = ap.parse_args()
 
+    from repro.runtime.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import kernel_bench as kb
     from benchmarks import paper_tables as pt
     from benchmarks import roofline_report as rr

@@ -66,6 +66,7 @@ from repro.serving import (
     TimedOut,
     WatchdogConfig,
 )
+from repro.runtime.compile_cache import enable_compile_cache
 
 #: repo-root perf-trajectory artifact (machine-readable baseline for future PRs)
 TRAJECTORY_PATH = os.path.join(
@@ -1749,6 +1750,7 @@ def _print(out):
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--smoke", action="store_true", help="tiny run for CI")
     ap.add_argument("--force", action="store_true", help="ignore cached JSON")

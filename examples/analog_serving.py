@@ -71,6 +71,7 @@ from repro.serving import (
     TierSpec,
     TimedOut,
 )
+from repro.runtime.compile_cache import enable_compile_cache
 
 CFG = ModelConfig(
     name="serve-demo", family="dense", n_layers=4, d_model=256, n_heads=8,
@@ -393,6 +394,7 @@ def _render_dashboard(feed, engine):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--energy", type=float, default=10.0, help="aJ per MAC")
     ap.add_argument("--batch", type=int, default=4)

@@ -20,6 +20,7 @@ from repro.models import energy_macs, init_params
 from repro.models.config import ModelConfig
 from repro.models.sharding import use_mesh
 from repro.optim.adam import AdamConfig, adam_init
+from repro.runtime.compile_cache import enable_compile_cache
 
 CFG = ModelConfig(
     name="calib-demo", family="dense", n_layers=4, d_model=256, n_heads=8,
@@ -29,6 +30,7 @@ CFG = ModelConfig(
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--target", type=float, default=2.0, help="aJ/MAC budget")
     ap.add_argument("--steps", type=int, default=60)

@@ -24,6 +24,9 @@ from repro.core import (
     uniform_log_energies,
 )
 from repro.data import make_tabular_dataset
+from repro.runtime.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 key = jax.random.PRNGKey(0)
 

@@ -19,6 +19,7 @@ from repro.data.pipeline import TokenTaskConfig
 from repro.launch.mesh import make_local_mesh
 from repro.launch.steps import TrainConfig
 from repro.models.config import ModelConfig
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.runtime.driver import DriverConfig, TrainDriver
 
 MODELS = {
@@ -36,6 +37,7 @@ MODELS = {
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--model", default="10m", choices=sorted(MODELS))
     ap.add_argument("--steps", type=int, default=200)

@@ -109,10 +109,7 @@ def key_batch(key: Optional[jax.Array]) -> Optional[int]:
     """
     if key is None:
         return None
-    try:
-        typed = jnp.issubdtype(key.dtype, jax.dtypes.prng_key)
-    except AttributeError:  # very old jax: only raw uint32 keys exist
-        typed = False
+    typed = jnp.issubdtype(key.dtype, jax.dtypes.prng_key)
     base_ndim = 0 if typed else 1
     if key.ndim == base_ndim:
         return None
@@ -131,11 +128,8 @@ def fold_key(key: jax.Array, data) -> jax.Array:
 def raw_key(key: jax.Array) -> jax.Array:
     """Normalize a (possibly typed) PRNG key to raw uint32 data — the
     stackable, ShapeDtypeStruct-able form the serving engine traffics in."""
-    try:
-        if jnp.issubdtype(key.dtype, jax.dtypes.prng_key):
-            return jax.random.key_data(key)
-    except AttributeError:  # very old jax: only raw uint32 keys exist
-        pass
+    if jnp.issubdtype(key.dtype, jax.dtypes.prng_key):
+        return jax.random.key_data(key)
     return key
 
 
@@ -231,7 +225,6 @@ def _maybe_sharded_analog_dot(
     if backend not in ("tile", "pallas"):
         return None
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.kernels import ops as kernel_ops
 
@@ -260,12 +253,12 @@ def _maybe_sharded_analog_dot(
         return jax.vmap(one)(xs, ks)
 
     out_spec = P(*([None] * (x.ndim - 1)), TP_AXIS)
-    y = shard_map(
+    y = jax.shard_map(
         shard,
         mesh=mesh,
         in_specs=(P(), P(None, TP_AXIS), P(), P()),
         out_specs=out_spec,
-        check_rep=False,
+        check_vma=False,
     )(x, w, kraw, e_arr)
     # Gather the column shards back to replicated: everything outside
     # analog_dot (residual adds, caches, AOT argument shardings) stays

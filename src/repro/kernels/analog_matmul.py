@@ -37,6 +37,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import prng
 
@@ -192,18 +193,10 @@ def analog_matmul_raw(
         n_repeats=n_repeats,
     )
     kwargs = {}
-    if not interpret:  # TPU compiler hints
-        try:
-            from jax.experimental.pallas import tpu as pltpu
-
-            params_cls = getattr(pltpu, "CompilerParams", None) or getattr(
-                pltpu, "TPUCompilerParams"
-            )
-            kwargs["compiler_params"] = params_cls(
-                dimension_semantics=("parallel", "parallel", "arbitrary")
-            )
-        except Exception:  # pragma: no cover - hint only
-            pass
+    if not interpret:
+        kwargs["compiler_params"] = pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")
+        )
 
     return pl.pallas_call(
         kern,

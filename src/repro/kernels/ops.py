@@ -32,6 +32,31 @@ def _ranges(sq, w, x) -> Tuple[Array, Array]:
     return w_rng, jnp.asarray(x_rng, jnp.float32)
 
 
+def _norm(v: Array, axis: int) -> Array:
+    """L2 norm along ``axis`` (kept, size 1), summed in a fixed order.
+
+    ``jnp.sum`` lowers to a reduction whose order the TPU compiler picks
+    by shape, layout and fusion, so a tensor-parallel shard's column slice
+    or a differently fused operand rounds differently from the unsharded
+    call. A pairwise tree of elementwise adds (zero-padded to a power of
+    two, which is exact) cannot be reordered: each norm depends only on its
+    own row or column. The noise scales of sharded and unsharded calls are
+    then bit-identical."""
+    v = v.astype(jnp.float32)
+    v = v * v
+    n = v.shape[axis]
+    width = 1 << (n - 1).bit_length()
+    if width != n:
+        pad = [(0, 0)] * v.ndim
+        pad[axis] = (0, width - n)
+        v = jnp.pad(v, pad)
+    while width > 1:
+        width //= 2
+        v = (jax.lax.slice_in_dim(v, 0, width, axis=axis)
+             + jax.lax.slice_in_dim(v, width, 2 * width, axis=axis))
+    return jnp.sqrt(v)
+
+
 def prepare_operands(
     x2d: Array, w: Array, *, energy, key, cfg, sq=None, offsets=(0, 0)
 ) -> dict:
@@ -61,10 +86,9 @@ def prepare_operands(
         row = ones_row
         noise_kind = "output"
     elif kind == noise_lib.SHOT:
-        w_col = jnp.linalg.norm(w.astype(jnp.float32), axis=0).reshape(1, -1)
         photons = e_col / cfg.noise.photon_energy_aj
-        col = w_col / jnp.sqrt(jnp.float32(k) * photons)
-        row = jnp.linalg.norm(x2d.astype(jnp.float32), axis=-1, keepdims=True)
+        col = _norm(w, axis=0) / jnp.sqrt(jnp.float32(k) * photons)
+        row = _norm(x2d, axis=-1)
         noise_kind = "output"
     elif kind == noise_lib.WEIGHT:
         w_rng, _ = _ranges(sq, w, x2d)

@@ -65,15 +65,24 @@ def threefry2x32(k0: Array, k1: Array, c0: Array, c1: Array) -> tuple[Array, Arr
     return x0, x1
 
 
+def _top24_float(bits: Array) -> Array:
+    """High 24 bits of uint32 words as exact float32 integers.
+
+    Converted through int32: Mosaic (the TPU kernel compiler) has no
+    uint32 -> float32 cast, and the shifted values are below 2^24, so the
+    int32 bitcast and the float conversion are both exact."""
+    shifted = bits >> jnp.uint32(8)
+    return jax.lax.bitcast_convert_type(shifted, jnp.int32).astype(jnp.float32)
+
+
 def bits_to_unit_open(bits: Array) -> Array:
     """uint32 -> float32 in (0, 1]: 1 - (bits >> 8) * 2^-24."""
-    u = (bits >> jnp.uint32(8)).astype(jnp.float32) * jnp.float32(2.0**-24)
-    return jnp.float32(1.0) - u
+    return jnp.float32(1.0) - _top24_float(bits) * jnp.float32(2.0**-24)
 
 
 def bits_to_unit_halfopen(bits: Array) -> Array:
     """uint32 -> float32 in [0, 1)."""
-    return (bits >> jnp.uint32(8)).astype(jnp.float32) * jnp.float32(2.0**-24)
+    return _top24_float(bits) * jnp.float32(2.0**-24)
 
 
 def counter_gaussian(k0: Array, k1: Array, c0: Array, c1: Array) -> Array:

@@ -13,21 +13,13 @@ TP ("model") stays intra-pod on ICI; batch/ZeRO sharding spans pod x data.
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
-
-try:  # jax >= 0.5: explicit axis types on the mesh
-    from jax.sharding import AxisType
-except ImportError:  # older jax: meshes are implicitly Auto
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 
 def _mesh(shape, axes) -> Mesh:
-    if AxisType is not None:
-        try:
-            return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
-        except TypeError:  # make_mesh predates the axis_types kwarg
-            pass
-    return jax.make_mesh(shape, axes)
+    # Auto axes: sharding constraints and shard_map partition these meshes;
+    # jax.make_mesh would default them to Explicit.
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

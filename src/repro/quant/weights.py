@@ -62,11 +62,16 @@ def _is_matmul_leaf(path: tuple, leaf: Array) -> bool:
 
 
 def quantize_params(params: PyTree) -> PyTree:
-    """bf16 param tree -> tree with Int8Weight matmul leaves."""
+    """bf16 param tree -> tree with Int8Weight matmul leaves.
+
+    Each leaf quantizes under ``jit``: fused, so a layer-stacked weight
+    never materializes as float32 (op-by-op it would need several f32
+    copies of the leaf on the device at once)."""
     flat = jax.tree_util.tree_flatten_with_path(params)
+    quantize = jax.jit(quantize_weight)
     out = []
     for path, leaf in flat[0]:
-        out.append(quantize_weight(leaf) if _is_matmul_leaf(path, leaf) else leaf)
+        out.append(quantize(leaf) if _is_matmul_leaf(path, leaf) else leaf)
     return jax.tree_util.tree_unflatten(flat[1], out)
 
 

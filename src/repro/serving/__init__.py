@@ -15,7 +15,7 @@ from repro.serving.bucketing import (
     pad_to_bucket,
     pool_shape,
 )
-from repro.serving.cache import ExecutableCache, aot_compile
+from repro.serving.cache import ExecutableBuildError, ExecutableCache, aot_compile
 from repro.serving.cluster import (
     ClusterGovernor,
     ClusterRouter,
@@ -74,6 +74,7 @@ __all__ = [
     "DigitalTier",
     "DriftEvent",
     "DriftRamp",
+    "ExecutableBuildError",
     "ExecutableCache",
     "ExecutionTier",
     "Failed",

@@ -41,6 +41,12 @@ def mesh_fingerprint(mesh) -> tuple:
     )
 
 
+class ExecutableBuildError(RuntimeError):
+    """An executable failed to lower or compile. This is a program error,
+    not a transient fault: the engine lets it propagate instead of
+    retrying the requests that needed the executable."""
+
+
 class ExecutableCache:
     """Maps hashable keys -> compiled executables, counting hits/misses.
 
@@ -100,7 +106,10 @@ class ExecutableCache:
             return self._guard(key, exe)
         self.misses += 1
         t0 = time.perf_counter()
-        exe = build()
+        try:
+            exe = build()
+        except Exception as e:
+            raise ExecutableBuildError(f"building {key!r} failed: {e}") from e
         dt = time.perf_counter() - t0
         self.compile_s += dt
         self.miss_log.append((key, dt))
@@ -110,6 +119,11 @@ class ExecutableCache:
                 self._exes.popitem(last=False)
                 self.evictions += 1
         return self._guard(key, exe)
+
+    def lookup(self, key: Hashable) -> Optional[Any]:
+        """The cached executable for ``key`` (unguarded), or None. Counts
+        nothing: for inspecting compiled programs, not for dispatch."""
+        return self._exes.get(key)
 
     def __len__(self) -> int:
         return len(self._exes)

@@ -87,7 +87,11 @@ from repro.serving.bucketing import (
     pad_to_bucket,
     pool_shape,
 )
-from repro.serving.cache import ExecutableCache, mesh_fingerprint
+from repro.serving.cache import (
+    ExecutableBuildError,
+    ExecutableCache,
+    mesh_fingerprint,
+)
 from repro.serving.faults import (
     BoundedLog,
     FaultPlan,
@@ -953,6 +957,8 @@ class ServingEngine:
         except TransientExecutableFault as f:
             self.stats["exe_faults"] += 1
             return self._fault_requeue(reqs, "exe_fault", str(f))
+        except ExecutableBuildError:
+            raise  # a program the compiler refuses fails on every retry
         except Exception as e:  # noqa: BLE001 - serving must not crash
             # an executable raising anything else mid-batch is contained
             # the same way: the batch retires into the bounded-retry path
@@ -1054,6 +1060,21 @@ class ServingEngine:
             self._pools[tier] = pool
         return pool
 
+    def _renew_donated_cache(
+        self, pool: DecodePool, detail: str
+    ) -> Dict[int, RequestResult]:
+        """After a decode or insert executable raised: if the call had
+        already consumed the pool's donated cache, every active row lost
+        its state. Those rows retire into the bounded-retry path and the
+        pool gets a fresh cache, so no later call reads a deleted buffer."""
+        if not any(a.is_deleted() for a in jax.tree.leaves(pool.cache)):
+            return {}
+        reqs = [pool.retire(s).request for s in pool.active_slots()]
+        self.stats["retired"] += len(reqs)
+        pool.cache = lm.init_cache(self.model_cfg, pool.slots, pool.cache_len)
+        pool.place_cache(self._replicate)
+        return self._fault_requeue(reqs, "exe_error", detail)
+
     @property
     def n_in_flight(self) -> int:
         """Requests submitted but not yet finished: queued + pooled."""
@@ -1137,6 +1158,8 @@ class ServingEngine:
         except TransientExecutableFault as f:
             self.stats["exe_faults"] += 1
             return self._fault_requeue(reqs, "exe_fault", str(f))
+        except ExecutableBuildError:
+            raise  # a program the compiler refuses fails on every retry
         except Exception as e:  # noqa: BLE001 - serving must not crash
             # exception safety at admission: no slot was taken yet, so an
             # executable raising anything mid-pump leaks nothing — the
@@ -1167,7 +1190,9 @@ class ServingEngine:
             for s in slots:
                 pool.release(s)
             self.stats["exe_errors"] += 1
-            return self._fault_requeue(reqs, "exe_error", repr(e))
+            out = self._renew_donated_cache(pool, repr(e))
+            out.update(self._fault_requeue(reqs, "exe_error", repr(e)))
+            return out
         self.stats["admitted"] += len(reqs)
         out: Dict[int, np.ndarray] = {}
         for i, (r, s) in enumerate(zip(reqs, slots)):
@@ -1249,6 +1274,7 @@ class ServingEngine:
                 self.stats["retired"] += 1
                 reqs.append(rec.request)
             out.update(self._fault_requeue(reqs, "exe_error", repr(e)))
+            out.update(self._renew_donated_cache(pool, repr(e)))
             return out
         tok_np = np.asarray(tok)
         if plan is not None and plan.poison_map:

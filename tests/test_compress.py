@@ -62,12 +62,11 @@ def test_compressed_psum_multidevice_subprocess():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
         from repro.optim.compress import compressed_psum
         mesh = jax.make_mesh((8,), ("d",))
         x = jax.random.normal(jax.random.PRNGKey(0), (8, 64))
-        f = shard_map(lambda v: compressed_psum(v[0], "d")[None],
-                      mesh=mesh, in_specs=P("d", None), out_specs=P("d", None))
+        f = jax.shard_map(lambda v: compressed_psum(v[0], "d")[None],
+                          mesh=mesh, in_specs=P("d", None), out_specs=P("d", None))
         got = np.asarray(f(x))
         want = np.asarray(jnp.sum(x, axis=0))
         # mean-scale reconstruction: ~1 int8 step of error per participant

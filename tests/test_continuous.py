@@ -15,7 +15,9 @@ from repro.core import AnalogConfig
 from repro.models import init_energy_tree, init_params, lm
 from repro.serving import (
     DecodePool,
+    ExecutableBuildError,
     ExecutableCache,
+    ExecutionTier,
     FaultPlan,
     PrecisionProfile,
     Request,
@@ -603,3 +605,68 @@ def test_faulted_pool_accounting_property(seed):
         assert pool.allocator.n_free == pool.slots
         assert not pool.allocator.held()
         assert (np.asarray(pool.lengths) == 0).all()  # all rows inert
+
+
+@pytest.mark.parametrize("continuous", [False, True])
+def test_compile_error_propagates_out_of_engine(continuous, monkeypatch):
+    """A program the compiler refuses is not a transient fault: retrying
+    cannot help, so the build error leaves the engine (under both decode
+    disciplines) instead of turning into retried, then Failed, requests
+    that a calling script would count as served."""
+    cfg = FAMILY_CONFIGS["dense"]
+    params = init_params(KEY, cfg)
+
+    def refuse(self, *shape):
+        raise NotImplementedError("Unsupported cast: uint32 -> float32")
+
+    monkeypatch.setattr(ExecutionTier, "build_prefill", refuse)
+    eng = ServingEngine(
+        params, cfg, max_gen=4, batch_buckets=(1, 2), seq_buckets=(SB,),
+        continuous=continuous,
+    )
+    eng.submit(np.arange(5), max_new_tokens=2)
+    with pytest.raises(ExecutableBuildError, match="Unsupported cast"):
+        eng.flush()
+    for stat in ("exe_errors", "exe_faults", "retried", "failed"):
+        assert eng.stats[stat] == 0, stat
+
+
+@pytest.mark.parametrize("phase", ["decode", "insert"])
+def test_consumed_donated_cache_is_renewed(phase, monkeypatch):
+    """An executable that raises after consuming its donated pool cache
+    (a device-side failure; the CPU ignores donation, so the test deletes
+    the buffer itself) leaves the pool no state to read: the engine gives
+    the pool a fresh cache and retries every row that lost its state, and
+    the retried requests still return their solo tokens."""
+    cfg = FAMILY_CONFIGS["dense"]
+    params = init_params(KEY, cfg)
+    eng = _continuous_engine(params, cfg, pool_slots=2)
+    get = eng.exe_cache.get
+    fired = []
+
+    def consuming_get(key, build):
+        exe = get(key, build)
+        if key[0] != phase:
+            return exe
+
+        def call(*args):
+            if not fired:
+                fired.append(key)
+                donated = args[1] if phase == "decode" else args[0]
+                for a in jax.tree.leaves(donated):
+                    a.delete()
+                raise RuntimeError("device fault after donation")
+            return exe(*args)
+
+        return call
+
+    monkeypatch.setattr(eng.exe_cache, "get", consuming_get)
+    prompts, gens, _ = _requests()
+    uids = [eng.submit(p, max_new_tokens=g) for p, g in zip(prompts, gens)]
+    results = eng.flush()
+    assert fired and eng.stats["exe_errors"] == 1
+    assert eng.stats["failed"] == 0
+    for uid, p, g in zip(uids, prompts, gens):
+        np.testing.assert_array_equal(
+            results[uid], _solo_tokens(params, cfg, p, g)
+        )

@@ -66,6 +66,7 @@ def test_sharded_train_step_matches_single_device():
         from repro.data.pipeline import TokenTaskConfig, markov_batch
         from repro.launch.steps import TrainConfig, make_train_step
         from repro.models import init_params
+        from repro.launch.mesh import make_mesh_for_devices
         from repro.models.sharding import use_mesh
         from repro.optim.adam import adam_init
 
@@ -74,8 +75,8 @@ def test_sharded_train_step_matches_single_device():
         batch = markov_batch(data, 0)
         tcfg = TrainConfig(lr=1e-3, opt_state_dtype="float32")
         results = {}
-        for shape, axes in (((1, 1), ("data", "model")), ((2, 4), ("data", "model"))):
-            mesh = jax.make_mesh(shape, axes)
+        for shape in ((1, 1), (2, 4)):
+            mesh = make_mesh_for_devices(shape[0] * shape[1], model_parallel=shape[1])
             with use_mesh(mesh):
                 params = init_params(jax.random.PRNGKey(0), cfg)
                 _, jit_for, _ = make_train_step(cfg, mesh, tcfg)
