@@ -18,8 +18,10 @@ next use, surfacing as a miss + eviction in ``stats()``).
 from __future__ import annotations
 
 import time
-from collections import OrderedDict, deque
-from typing import Any, Callable, Deque, Hashable, Optional
+from collections import OrderedDict
+from typing import Any, Callable, Hashable, Optional
+
+from repro.serving import trace
 
 
 def mesh_fingerprint(mesh) -> tuple:
@@ -73,17 +75,6 @@ class ExecutableCache:
         self.misses = 0
         self.evictions = 0
         self.compile_s = 0.0
-        #: per-miss records [(key, seconds)] — the bench's retrace audit
-        #: trail. A bounded cache churns executables (eviction -> recompile
-        #: -> fresh miss), so the log is capped there too: an unbounded log
-        #: would leak host memory linearly in misses while the executable
-        #: dict itself stays at max_entries.
-        self.miss_log: Deque[tuple] = deque(maxlen=self._miss_log_cap())
-
-    def _miss_log_cap(self) -> Optional[int]:
-        if self.max_entries is None:
-            return None  # unbounded cache: every miss is a one-time compile
-        return max(64, 4 * self.max_entries)
 
     def _guard(self, key: Hashable, exe: Any) -> Any:
         """Wrap an executable so ``fault_hook(key)`` runs before dispatch."""
@@ -106,13 +97,13 @@ class ExecutableCache:
             return self._guard(key, exe)
         self.misses += 1
         t0 = time.perf_counter()
-        try:
-            exe = build()
-        except Exception as e:
-            raise ExecutableBuildError(f"building {key!r} failed: {e}") from e
-        dt = time.perf_counter() - t0
-        self.compile_s += dt
-        self.miss_log.append((key, dt))
+        # a miss is a compile: the span names it on the device trace's clock
+        with trace.span(trace.COMPILE, key=lambda: trace.text(key)):
+            try:
+                exe = build()
+            except Exception as e:
+                raise ExecutableBuildError(f"building {key!r} failed: {e}") from e
+        self.compile_s += time.perf_counter() - t0
         self._exes[key] = exe
         if self.max_entries is not None:
             while len(self._exes) > self.max_entries:
@@ -137,7 +128,6 @@ class ExecutableCache:
         self.misses = 0
         self.evictions = 0
         self.compile_s = 0.0
-        self.miss_log = deque(maxlen=self._miss_log_cap())
 
     def stats(self) -> dict:
         total = self.hits + self.misses

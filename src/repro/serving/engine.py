@@ -79,6 +79,7 @@ from repro.core.analog import AnalogConfig, raw_key
 from repro.core.profile import PrecisionProfile
 from repro.models import lm
 from repro.models.config import ModelConfig
+from repro.serving import trace
 from repro.serving.bucketing import (
     DEFAULT_BATCH_BUCKETS,
     DEFAULT_SEQ_BUCKETS,
@@ -606,7 +607,8 @@ class ServingEngine:
             ),
         )
         req.retier(tier_id)
-        self.scheduler.submit(req)
+        with trace.span(trace.SUBMIT, uid=uid):
+            self.scheduler.submit(req)
         self.stats["requests"] += 1
         return uid
 
@@ -930,19 +932,24 @@ class ServingEngine:
         )
         if cache_len is None:
             cache_len = sb + self.max_gen
-        tokens_np, lengths_np = pad_to_bucket(
-            [r.tokens for r in reqs], (bb, sb), pad_id=self.pad_id
-        )
-        keys = self._batch_keys(reqs, bb)
-        prefill_exe = self.exe_cache.get(
-            self.tiers.exe_key("prefill", tier, bb, sb, cache_len),
-            lambda: t.build_prefill(bb, sb, cache_len),
-        )
-        self._sync_noise_scale()
-        cache, tok = prefill_exe(
-            t.params, jnp.asarray(tokens_np), jnp.asarray(lengths_np), keys,
-            self._scale_arr(),
-        )
+        with trace.span(
+            trace.PREFILL, tier=lambda: trace.text(tier), bb=bb, sb=sb,
+            tokens=lambda: sum(r.prompt_len for r in reqs),
+            uids=lambda: " ".join(str(r.uid) for r in reqs),
+        ):
+            tokens_np, lengths_np = pad_to_bucket(
+                [r.tokens for r in reqs], (bb, sb), pad_id=self.pad_id
+            )
+            keys = self._batch_keys(reqs, bb)
+            prefill_exe = self.exe_cache.get(
+                self.tiers.exe_key("prefill", tier, bb, sb, cache_len),
+                lambda: t.build_prefill(bb, sb, cache_len),
+            )
+            self._sync_noise_scale()
+            cache, tok = prefill_exe(
+                t.params, jnp.asarray(tokens_np), jnp.asarray(lengths_np), keys,
+                self._scale_arr(),
+            )
         self.stats["batches"] += 1
         self.stats["padded_rows"] += bb - len(reqs)
         return (bb, sb, cache_len), keys, cache, tok
@@ -1116,30 +1123,32 @@ class ServingEngine:
         (``now=None`` flush drains everything and times out nothing).
         """
         results: Dict[int, RequestResult] = {}
-        progressed = False
-        results.update(self._expire_queued(now))
-        results.update(self._expire_pooled(now))
-        if results:
-            progressed = True
-        if self.governor is not None and not force:
-            # one policy step per pump round: demotions land *before*
-            # admission, so retiered requests prefill into their new tier's
-            # pool this very round (flush keeps requests as-submitted)
-            self.governor.step(now)
-        free = {}
-        for tier in self.scheduler.pending_tiers():
-            pool = self._pools.get(tier)
-            free[tier] = pool.n_free if pool is not None else self.pool_slots
-        for reqs in self.scheduler.pop_admissible(now, free, force=force):
-            results.update(self._admit(reqs))
-            progressed = True
-        for pool in self._pools.values():
-            if pool.n_active:
-                results.update(self._pool_step(pool))
+        with trace.span(trace.PUMP):
+            with trace.span(trace.SCHEDULE):
+                results.update(self._expire_queued(now))
+                results.update(self._expire_pooled(now))
+                progressed = bool(results)
+                if self.governor is not None and not force:
+                    # one policy step per pump round: demotions land *before*
+                    # admission, so retiered requests prefill into their new
+                    # tier's pool this very round (flush keeps requests
+                    # as-submitted)
+                    self.governor.step(now)
+                free = {}
+                for tier in self.scheduler.pending_tiers():
+                    pool = self._pools.get(tier)
+                    free[tier] = pool.n_free if pool is not None else self.pool_slots
+                admissible = self.scheduler.pop_admissible(now, free, force=force)
+            for reqs in admissible:
+                results.update(self._admit(reqs))
                 progressed = True
-        if self.metrics is not None:
-            # one observability sample per pump round: the feed's time base
-            self.metrics.record(self, now=now)
+            for pool in self._pools.values():
+                if pool.n_active:
+                    results.update(self._pool_step(pool))
+                    progressed = True
+            if self.metrics is not None:
+                # one observability sample per pump round: the feed's time base
+                self.metrics.record(self, now=now)
         return results, progressed
 
     def _admit(self, reqs: List[Request]) -> Dict[int, RequestResult]:
@@ -1166,33 +1175,35 @@ class ServingEngine:
             # wave retires into the bounded-retry path exactly once
             self.stats["exe_errors"] += 1
             return self._fault_requeue(reqs, "exe_error", repr(e))
-        tok0 = np.asarray(tok0)  # admission bookkeeping needs host values
+        with trace.span(trace.PREFILL_WAIT):
+            tok0 = np.asarray(tok0)  # admission bookkeeping needs host values
         slots = pool.take(len(reqs))
         # prefill batch-padding rows aim past the pool: dropped by the scatter
         slot_ids = np.full((bb,), pool.slots, np.int32)
         slot_ids[: len(reqs)] = slots
         # tier-free key: the cache layout is parameter- and noise-free, so
         # one insert executable is shared across every tier's pool shape
-        insert_exe = self.exe_cache.get(
-            self.tiers.exe_key("insert", None, pool.slots, pool.cache_len, bb),
-            lambda: pool.exec_tier.build_insert(pool.slots, pool.cache_len, bb),
-        )
-        try:
-            pool.cache = insert_exe(pool.cache, src_cache, jnp.asarray(slot_ids))
-        except TransientExecutableFault as f:
-            for s in slots:
-                pool.release(s)
-            self.stats["exe_faults"] += 1
-            return self._fault_requeue(reqs, "exe_fault", str(f))
-        except Exception as e:  # noqa: BLE001 - serving must not crash
-            # taken slots are released before the requeue: a raising
-            # insert neither leaks nor aliases pool slots
-            for s in slots:
-                pool.release(s)
-            self.stats["exe_errors"] += 1
-            out = self._renew_donated_cache(pool, repr(e))
-            out.update(self._fault_requeue(reqs, "exe_error", repr(e)))
-            return out
+        with trace.span(trace.INSERT, bb=bb):
+            insert_exe = self.exe_cache.get(
+                self.tiers.exe_key("insert", None, pool.slots, pool.cache_len, bb),
+                lambda: pool.exec_tier.build_insert(pool.slots, pool.cache_len, bb),
+            )
+            try:
+                pool.cache = insert_exe(pool.cache, src_cache, jnp.asarray(slot_ids))
+            except TransientExecutableFault as f:
+                for s in slots:
+                    pool.release(s)
+                self.stats["exe_faults"] += 1
+                return self._fault_requeue(reqs, "exe_fault", str(f))
+            except Exception as e:  # noqa: BLE001 - serving must not crash
+                # taken slots are released before the requeue: a raising
+                # insert neither leaks nor aliases pool slots
+                for s in slots:
+                    pool.release(s)
+                self.stats["exe_errors"] += 1
+                out = self._renew_donated_cache(pool, repr(e))
+                out.update(self._fault_requeue(reqs, "exe_error", repr(e)))
+                return out
         self.stats["admitted"] += len(reqs)
         out: Dict[int, np.ndarray] = {}
         for i, (r, s) in enumerate(zip(reqs, slots)):
@@ -1237,78 +1248,82 @@ class ServingEngine:
         # the pool carries its ExecutionTier object (the registry is
         # add-only, so the reference can't drift from it)
         t = pool.exec_tier
-        decode_exe = self.exe_cache.get(
-            self.tiers.exe_key("decode", pool.tier, pool.slots, pool.cache_len),
-            lambda: t.build_decode(pool.slots, pool.cache_len),
-        )
-        self._sync_noise_scale()
-        try:
-            tok, pool.cache = decode_exe(
-                t.params,
-                pool.cache,
-                jnp.asarray(pool.tok[:, None]),
-                jnp.asarray(pool.pos),
-                jnp.asarray(pool.lengths),
-                jnp.asarray(pool.keys),
-                self._scale_arr(),
+        with trace.span(trace.DECODE, tier=lambda: trace.text(pool.tier),
+                        slots=pool.slots, active=pool.n_active):
+            decode_exe = self.exe_cache.get(
+                self.tiers.exe_key("decode", pool.tier, pool.slots, pool.cache_len),
+                lambda: t.build_decode(pool.slots, pool.cache_len),
             )
-        except TransientExecutableFault as f:
-            self.stats["exe_faults"] += 1
+            self._sync_noise_scale()
+            try:
+                tok, pool.cache = decode_exe(
+                    t.params,
+                    pool.cache,
+                    jnp.asarray(pool.tok[:, None]),
+                    jnp.asarray(pool.pos),
+                    jnp.asarray(pool.lengths),
+                    jnp.asarray(pool.keys),
+                    self._scale_arr(),
+                )
+            except TransientExecutableFault as f:
+                self.stats["exe_faults"] += 1
+                out: Dict[int, RequestResult] = {}
+                reqs = []
+                for s in pool.active_slots():
+                    rec = pool.retire(s)
+                    self.stats["retired"] += 1
+                    reqs.append(rec.request)
+                out.update(self._fault_requeue(reqs, "exe_fault", str(f)))
+                return out
+            except Exception as e:  # noqa: BLE001 - serving must not crash
+                # same containment for an executable raising anything else:
+                # every active row retires (slots freed, never aliased) and
+                # re-enters through the bounded-retry path exactly once
+                self.stats["exe_errors"] += 1
+                out: Dict[int, RequestResult] = {}
+                reqs = []
+                for s in pool.active_slots():
+                    rec = pool.retire(s)
+                    self.stats["retired"] += 1
+                    reqs.append(rec.request)
+                out.update(self._fault_requeue(reqs, "exe_error", repr(e)))
+                out.update(self._renew_donated_cache(pool, repr(e)))
+                return out
+        with trace.span(trace.DECODE_WAIT):
+            tok_np = np.asarray(tok)
+        with trace.span(trace.RETIRE):
+            if plan is not None and plan.poison_map:
+                tok_np = tok_np.copy()  # device views are read-only
+                plan.poison_rows(clock, tok_np)  # detected below by value
+            self.stats["decode_steps"] += 1
+            self.stats["decode_slot_steps"] += pool.slots
+            self.stats["active_slot_steps"] += pool.n_active
+            self._bump_tier("tier_decode_steps", pool.tier, 1)
             out: Dict[int, RequestResult] = {}
-            reqs = []
+            poisoned_reqs: List[Request] = []
+            vocab = self.model_cfg.vocab_size
             for s in pool.active_slots():
-                rec = pool.retire(s)
-                self.stats["retired"] += 1
-                reqs.append(rec.request)
-            out.update(self._fault_requeue(reqs, "exe_fault", str(f)))
-            return out
-        except Exception as e:  # noqa: BLE001 - serving must not crash
-            # same containment for an executable raising anything else:
-            # every active row retires (slots freed, never aliased) and
-            # re-enters through the bounded-retry path exactly once
-            self.stats["exe_errors"] += 1
-            out: Dict[int, RequestResult] = {}
-            reqs = []
-            for s in pool.active_slots():
-                rec = pool.retire(s)
-                self.stats["retired"] += 1
-                reqs.append(rec.request)
-            out.update(self._fault_requeue(reqs, "exe_error", repr(e)))
-            out.update(self._renew_donated_cache(pool, repr(e)))
-            return out
-        tok_np = np.asarray(tok)
-        if plan is not None and plan.poison_map:
-            tok_np = tok_np.copy()  # device views are read-only
-            plan.poison_rows(clock, tok_np)  # detected below by value
-        self.stats["decode_steps"] += 1
-        self.stats["decode_slot_steps"] += pool.slots
-        self.stats["active_slot_steps"] += pool.n_active
-        self._bump_tier("tier_decode_steps", pool.tier, 1)
-        out: Dict[int, RequestResult] = {}
-        poisoned_reqs: List[Request] = []
-        vocab = self.model_cfg.vocab_size
-        for s in pool.active_slots():
-            t = int(tok_np[s])
-            if not 0 <= t < vocab:
-                # corrupted readout: retire the row alone; its batch-mates'
-                # noise streams never depended on it
-                rec = pool.retire(s)
-                self.stats["poisoned_rows"] += 1
-                self.stats["retired"] += 1
-                poisoned_reqs.append(rec.request)
-                continue
-            rec = pool.record(s)
-            rec.emitted.append(t)
-            pool.tok[s] = t
-            pool.pos[s] += 1
-            if rec.done:
-                pool.retire(s)
-                out[rec.request.uid] = np.asarray(rec.emitted, np.int32)
-                self.stats["tokens_generated"] += len(rec.emitted)
-                self._bump_tier("tier_tokens", pool.tier, len(rec.emitted))
-                self.stats["retired"] += 1
-        for r in poisoned_reqs:
-            out.update(self._fault_requeue([r], "poison", "out-of-vocab token"))
+                t = int(tok_np[s])
+                if not 0 <= t < vocab:
+                    # corrupted readout: retire the row alone; its batch-mates'
+                    # noise streams never depended on it
+                    rec = pool.retire(s)
+                    self.stats["poisoned_rows"] += 1
+                    self.stats["retired"] += 1
+                    poisoned_reqs.append(rec.request)
+                    continue
+                rec = pool.record(s)
+                rec.emitted.append(t)
+                pool.tok[s] = t
+                pool.pos[s] += 1
+                if rec.done:
+                    pool.retire(s)
+                    out[rec.request.uid] = np.asarray(rec.emitted, np.int32)
+                    self.stats["tokens_generated"] += len(rec.emitted)
+                    self._bump_tier("tier_tokens", pool.tier, len(rec.emitted))
+                    self.stats["retired"] += 1
+            for r in poisoned_reqs:
+                out.update(self._fault_requeue([r], "poison", "out-of-vocab token"))
         return out
 
     # -- introspection -------------------------------------------------------

@@ -173,7 +173,9 @@ class ExecutionTier:
         eng = self.engine
         cfg = eng.model_cfg
 
-        def fn(params, tokens, lengths, keys, noise_scale):
+        # the function's name names the HLO module (``jit_prefill``; likewise
+        # ``jit_decode`` and ``jit_insert``), which a device trace shows
+        def prefill(params, tokens, lengths, keys, noise_scale):
             eng._traces += 1  # runs at trace time only: the retrace audit
             analog = self.analog_spec(keys, noise_scale=noise_scale)
             cache, h_last = lm.prefill(
@@ -187,7 +189,7 @@ class ExecutionTier:
         i32 = jnp.int32
         with eng._mesh_ctx():
             return aot_compile(
-                fn,
+                prefill,
                 self._pin(self.param_specs),
                 self._sds((bb, sb), i32),
                 self._sds((bb,), i32),
@@ -200,7 +202,7 @@ class ExecutionTier:
         eng = self.engine
         cfg = eng.model_cfg
 
-        def fn(params, cache, tok, pos, lengths, keys, noise_scale):
+        def decode(params, cache, tok, pos, lengths, keys, noise_scale):
             eng._traces += 1
             analog = self.analog_spec(keys, pos=pos, noise_scale=noise_scale)
             logits, new_cache = lm.decode_step(
@@ -214,7 +216,7 @@ class ExecutionTier:
         cache_specs = jax.eval_shape(lambda: lm.init_cache(cfg, bb, cache_len))
         with eng._mesh_ctx():
             return aot_compile(
-                fn,
+                decode,
                 self._pin(self.param_specs),
                 self._pin(cache_specs),
                 self._sds((bb, 1), i32),
@@ -236,7 +238,7 @@ class ExecutionTier:
         eng = self.engine
         cfg = eng.model_cfg
 
-        def fn(pool_cache, src_cache, slot_ids):
+        def insert(pool_cache, src_cache, slot_ids):
             eng._traces += 1
             return lm.scatter_cache_rows(cfg, pool_cache, src_cache, slot_ids)
 
@@ -244,7 +246,7 @@ class ExecutionTier:
         src_specs = jax.eval_shape(lambda: lm.init_cache(cfg, bb, cache_len))
         with eng._mesh_ctx():
             return aot_compile(
-                fn,
+                insert,
                 self._pin(pool_specs),
                 self._pin(src_specs),
                 self._sds((bb,), jnp.int32),
