@@ -172,6 +172,10 @@ def run_one_chip(cfg, seed: int) -> None:
         n_calls = exe.as_text().count("tpu_custom_call")
         print(f"K={tier} prefill program: {n_calls} tpu_custom_call site(s)")
         check(n_calls > 0, f"K={tier} prefill ran no compiled Pallas kernel")
+    from repro.kernels.analog_matmul import NOISE_PLANS
+
+    print("output-noise plans of the traced kernels ((rows, steps) or finish): "
+          + ", ".join(f"{plan}: {n}" for plan, n in sorted(NOISE_PLANS.items(), key=str)))
 
     uid = engine.submit(prompts[0], tier=1, max_new_tokens=NEW_TOKENS, key=keys[0])
     solo = engine.flush()[uid]

@@ -28,9 +28,18 @@ noise, so the kernel draws K independent gaussian tiles per output/weight
 tile (salted by repeat index), averages them in-register, and applies them
 in a SINGLE matmul pass: one x/w HBM read and one y write regardless of K.
 The K-fold tiled operands of the explicit form never exist.
+
+Output noise is drawn alongside the matmul, not after it: with several
+k-steps per output tile, each step draws one row slice of the tile's
+repeat-averaged noise into a VMEM scratch (``noise_plan``), in the same
+straight-line block as that step's matmul, so the VPU's Threefry work and the
+MXU's accumulation can be scheduled together. The finish step only reads the
+scratch. Every element's draw is the same call with the same counters, so the
+output is bit-identical to drawing the whole tile at the finish.
 """
 from __future__ import annotations
 
+import collections
 import functools
 from typing import Optional
 
@@ -44,6 +53,35 @@ from repro.kernels import prng
 Array = jax.Array
 
 DEFAULT_BLOCK = (256, 256, 512)  # (bm, bn, bk)
+
+#: kernel instantiations (trace time) by ``noise_plan``: "finish" or
+#: (rows per slice, steps that draw). Output-noise calls only.
+NOISE_PLANS: collections.Counter = collections.Counter()
+
+
+def _clip_block(block: tuple, m: int, k: int, n: int) -> tuple:
+    bm, bn, bk = block
+    return min(bm, m), min(bn, n), min(bk, k)
+
+
+def noise_plan(m: int, k: int, n: int, block: tuple = DEFAULT_BLOCK,
+               noise_kind: str = "output"):
+    """Where an (m, k) @ (k, n) call draws each output tile's noise.
+
+    None without output noise. "finish" with one k-step: the whole tile is
+    drawn after its only matmul. Otherwise ``(c, steps)``: k-step ``tk <
+    steps`` draws tile rows ``[tk*c, min(tk*c + c, bm))``, with ``c`` the
+    tile's rows over the k-steps rounded up to the 8-row sublane tiling,
+    and the later steps draw nothing.
+    """
+    if noise_kind != "output":
+        return None
+    bm, _, bk = _clip_block(block, m, k, n)
+    nk = pl.cdiv(k, bk)
+    if nk == 1:
+        return "finish"
+    c = min(pl.cdiv(pl.cdiv(bm, nk), 8) * 8, bm)
+    return c, pl.cdiv(bm, c)
 
 
 def _fake_quant(v: Array, delta: Array, zp: Array, bins: Array) -> Array:
@@ -62,8 +100,9 @@ def _kernel(
     sc_ref,
     seed_ref,
     out_ref,
-    *,
+    *scratch,
     noise_kind: str,
+    plan,
     nk: int,
     block: tuple,
     k_total: int,
@@ -84,60 +123,97 @@ def _kernel(
     # its tile of the global stream ((0, 0) for whole-array calls).
     row0, col0 = seed[0, 2], seed[0, 3]
 
+    def output_noise(r0, rows):
+        # K repeat draws averaged in-register for tile rows [r0, r0 + rows):
+        # one matmul pass, zero extra HBM traffic for the dynamic-precision
+        # redundancy.
+        return prng.repeat_averaged_gaussian_tile(
+            k0,
+            k1,
+            row0 + jnp.asarray(ti * bm + r0, jnp.uint32),
+            col0 + jnp.asarray(tj * bn, jnp.uint32),
+            (rows, bn),
+            n_repeats,
+        )
+
     @pl.when(tk == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    xb = x_ref[...].astype(jnp.float32)
-    wb = w_ref[...].astype(jnp.float32)
+    def accumulate():
+        xb = x_ref[...].astype(jnp.float32)
+        wb = w_ref[...].astype(jnp.float32)
 
-    if k_total % bk != 0:
-        # Mask the K-tail: out-of-bounds block regions are undefined (NaN in
-        # interpret mode) and must not feed the accumulation.
-        k_idx = jax.lax.broadcasted_iota(jnp.int32, (bm, bk), 1) + tk * bk
-        xb = jnp.where(k_idx < k_total, xb, 0.0)
-        wk_idx = jax.lax.broadcasted_iota(jnp.int32, (bk, bn), 0) + tk * bk
-        wb = jnp.where(wk_idx < k_total, wb, 0.0)
+        if k_total % bk != 0:
+            # Mask the K-tail: out-of-bounds block regions are undefined (NaN
+            # in interpret mode) and must not feed the accumulation.
+            k_idx = jax.lax.broadcasted_iota(jnp.int32, (bm, bk), 1) + tk * bk
+            xb = jnp.where(k_idx < k_total, xb, 0.0)
+            wk_idx = jax.lax.broadcasted_iota(jnp.int32, (bk, bn), 0) + tk * bk
+            wb = jnp.where(wk_idx < k_total, wb, 0.0)
 
-    if quant_x:
-        xb = _fake_quant(xb, sc[0, 0], sc[0, 1], sc[0, 2])
-    if quant_w:
-        wd = wq_ref[0:1, :]  # (1, bn) per-channel delta
-        wz = wq_ref[1:2, :]
-        wbins = wq_ref[2:3, :]
-        wb = _fake_quant(wb, wd, wz, wbins)
-    if noise_kind == "weight":
-        # std per column lives in cs; counter = (global k, global j); the
-        # salt decorrelates this stream from the output-noise stream. With
-        # n_repeats > 1 the K independent device reads are averaged here in
-        # VMEM — the (K*k, N) tiled weight array never exists.
-        xi = prng.repeat_averaged_gaussian_tile(
-            k0 ^ jnp.uint32(prng.WEIGHT_STREAM_SALT),
-            k1,
-            jnp.asarray(tk * bk, jnp.uint32),
-            col0 + jnp.asarray(tj * bn, jnp.uint32),
-            (bk, bn),
-            n_repeats,
-        )
-        wb = wb + cs_ref[...] * xi
+        if quant_x:
+            xb = _fake_quant(xb, sc[0, 0], sc[0, 1], sc[0, 2])
+        if quant_w:
+            wd = wq_ref[0:1, :]  # (1, bn) per-channel delta
+            wz = wq_ref[1:2, :]
+            wbins = wq_ref[2:3, :]
+            wb = _fake_quant(wb, wd, wz, wbins)
+        if noise_kind == "weight":
+            # std per column lives in cs; counter = (global k, global j); the
+            # salt decorrelates this stream from the output-noise stream.
+            # With n_repeats > 1 the K independent device reads are averaged
+            # here in VMEM -- the (K*k, N) tiled weight array never exists.
+            xi = prng.repeat_averaged_gaussian_tile(
+                k0 ^ jnp.uint32(prng.WEIGHT_STREAM_SALT),
+                k1,
+                jnp.asarray(tk * bk, jnp.uint32),
+                col0 + jnp.asarray(tj * bn, jnp.uint32),
+                (bk, bn),
+                n_repeats,
+            )
+            wb = wb + cs_ref[...] * xi
 
-    out_ref[...] += jnp.dot(xb, wb, preferred_element_type=jnp.float32)
+        out_ref[...] += jnp.dot(xb, wb, preferred_element_type=jnp.float32)
+
+    if isinstance(plan, tuple):
+        # Each step's noise slice is drawn in the same block as its matmul:
+        # a branch around the draw alone would give it a block of its own,
+        # which the compiler does not interleave with the matmul. So the
+        # matmul is emitted once per run of steps that draw alike.
+        (noise_ref,) = scratch
+        c, steps = plan
+        tail = bm - (steps - 1) * c
+
+        def draw(rows):
+            start = tk * c
+            if c % 8 == 0:
+                start = pl.multiple_of(start, 8)
+            noise_ref[pl.ds(start, rows), :] = output_noise(tk * c, rows)
+
+        full = steps if tail == c else steps - 1
+        runs = [(0, full, c), (full, steps, tail), (steps, nk, 0)]
+        runs = [r for r in runs if r[0] < r[1]]
+        for lo, hi, rows in runs:
+            def step(rows=rows):
+                if rows:
+                    draw(rows)
+                accumulate()
+
+            if len(runs) == 1:
+                step()
+            else:
+                pl.when((tk >= lo) & (tk < hi))(step)
+    else:
+        accumulate()
 
     @pl.when(tk == nk - 1)
     def _finish():
         y = out_ref[...]
-        if noise_kind == "output":
-            # K repeat draws averaged in-register: one matmul pass, zero
-            # extra HBM traffic for the dynamic-precision redundancy.
-            xi = prng.repeat_averaged_gaussian_tile(
-                k0,
-                k1,
-                row0 + jnp.asarray(ti * bm, jnp.uint32),
-                col0 + jnp.asarray(tj * bn, jnp.uint32),
-                (bm, bn),
-                n_repeats,
-            )
-            y = y + rs_ref[...] * cs_ref[...] * xi
+        if plan == "finish":
+            y = y + rs_ref[...] * cs_ref[...] * output_noise(0, bm)
+        elif isinstance(plan, tuple):
+            y = y + rs_ref[...] * cs_ref[...] * noise_ref[...]
         if quant_out:
             y = _fake_quant(y, sc[0, 3], sc[0, 4], sc[0, 5])
         out_ref[...] = y
@@ -177,13 +253,17 @@ def analog_matmul_raw(
     assert n_repeats >= 1, n_repeats
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
-    bm, bn, bk = block
-    bm, bn, bk = min(bm, m), min(bn, n), min(bk, k)
+    bm, bn, bk = _clip_block(block, m, k, n)
     grid = (pl.cdiv(m, bm), pl.cdiv(n, bn), pl.cdiv(k, bk))
+    plan = noise_plan(m, k, n, block, noise_kind)
+    if plan is not None:
+        NOISE_PLANS[plan] += 1
+    scratch = [pltpu.VMEM((bm, bn), jnp.float32)] if isinstance(plan, tuple) else []
 
     kern = functools.partial(
         _kernel,
         noise_kind=noise_kind,
+        plan=plan,
         nk=grid[2],
         block=(bm, bn, bk),
         k_total=k,
@@ -212,6 +292,7 @@ def analog_matmul_raw(
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
+        scratch_shapes=scratch,
         interpret=interpret,
         **kwargs,
     )(

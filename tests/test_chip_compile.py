@@ -58,6 +58,9 @@ CASES = {
     "output_K4_ffn": (256, 4096, 12800, "output", 4, None),
     "weight_K1": (256, 4096, 4096, "weight", 1, None),
     "output_K4_per_request": (256, 4096, 4096, "output", 4, 4),
+    # the docs cell's prefill: noise slices drawn across 8 and 25 k-steps
+    "output_K8_docs_ffn": (512, 4096, 12800, "output", 8, 4),
+    "output_K8_docs_down": (512, 12800, 4096, "output", 8, 4),
 }
 
 

@@ -7,8 +7,11 @@ Covers the acceptance criteria of the fused-execution refactor:
     reproduce the full-array draw exactly (the kernel/oracle contract);
   * fused K-repeat variance matches the explicit O(K) time-averaging oracle;
   * AnalogHook reaches the Pallas kernel under backend="pallas";
-  * the analytic HBM traffic of the fused form is independent of K.
+  * the analytic HBM traffic of the fused form is independent of K;
+  * output noise drawn in row slices across the k-steps is bit-identical
+    to the oracle's whole-array draw, and the kernel records its plan.
 """
+import collections
 import os
 import sys
 
@@ -25,6 +28,12 @@ from repro.core.redundant import (
     time_averaged_dot_explicit,
 )
 from repro.kernels import analog_matmul, analog_matmul_reference
+from repro.kernels.analog_matmul import (
+    DEFAULT_BLOCK,
+    NOISE_PLANS,
+    analog_matmul_raw,
+    noise_plan,
+)
 from repro.kernels.dispatch import resolve_backend
 from repro.kernels.prng import repeat_averaged_gaussian_tile, repeat_key
 from repro.models.hooks import AnalogHook
@@ -242,3 +251,75 @@ def test_analytic_traffic_fused_independent_of_k():
     assert t1["hbm_bytes_fused"] == t16["hbm_bytes_fused"]
     ratio = t16["hbm_bytes_unfused"] / t1["hbm_bytes_unfused"]
     assert ratio == pytest.approx(16.0, rel=0.1)
+
+
+# ---------------------------------------------------------------------------
+# output noise drawn across the k-steps
+# ---------------------------------------------------------------------------
+
+#: (m, k, n, block, plan): two row and two column tiles each, so the row
+#: and column tile offsets of the counters are exercised.
+SPREAD_CASES = {
+    "nk1_finish": (128, 96, 80, (64, 40, 128), "finish"),
+    "nk3_exact": (144, 96, 80, (72, 40, 32), (24, 3)),  # 72 / 3 = 24 rows
+    "nk3_rounded": (128, 96, 80, (64, 40, 32), (24, 3)),  # 22 -> 24, tail 16
+    "nk25_rounded": (512, 200, 80, (256, 40, 8), (16, 16)),  # 11 -> 16, 9 idle
+}
+
+
+@pytest.mark.parametrize("k_rep", [1, 8])
+@pytest.mark.parametrize("case", SPREAD_CASES)
+def test_spread_output_noise_bit_exact(case, k_rep):
+    """Thermal noise with x = 0 leaves y = col_scale * xi exactly, so the
+    kernel's noise, drawn slice by slice into its scratch, must equal the
+    oracle's whole-array draw bit for bit, at a nonzero column origin.
+    Both sides are compiled: run op by op, the oracle rounds each repeat's
+    add on its own, where compiled code may contract the multiply-adds of
+    the repeat average (the kernel always runs compiled)."""
+    m, k, n, block, plan = SPREAD_CASES[case]
+    assert noise_plan(m, k, n, block) == plan
+    cfg = AnalogConfig.thermal(0.01, weight_bits=None, act_bits=None, out_bits=None)
+    # the activation range sets the thermal scale; x itself is zero
+    sq = SiteQuant(xqp=calibrate_minmax(jax.random.normal(KEY, (m, k))))
+    x = jnp.zeros((m, k), jnp.float32)
+    w = jax.random.normal(jax.random.fold_in(KEY, 1), (k, n)) * 0.2
+    kw = dict(energy=jnp.asarray(4.0), cfg=cfg, sq=sq, n_repeats=k_rep, offsets=(0, 40))
+
+    yk = jax.jit(lambda x, w, key: analog_matmul(x, w, key=key, block=block, **kw))(x, w, KEY)
+    yr = jax.jit(lambda x, w, key: analog_matmul_reference(x, w, key=key, **kw))(x, w, KEY)
+    assert float(jnp.abs(yr).min()) > 0  # every element holds noise
+    np.testing.assert_array_equal(np.asarray(yk), np.asarray(yr))
+
+
+def _trace_kernel(m, k, n, noise_kind="output", n_repeats=8):
+    f32 = jnp.float32
+    args = [
+        jax.ShapeDtypeStruct(s, d)
+        for s, d in [((m, k), jnp.bfloat16), ((k, n), jnp.bfloat16), ((m, 1), f32),
+                     ((1, n), f32), ((3, n), f32), ((1, 8), f32), ((1, 4), jnp.uint32)]
+    ]
+    jax.eval_shape(
+        lambda *a: analog_matmul_raw(*a, noise_kind=noise_kind, n_repeats=n_repeats),
+        *args,
+    )
+
+
+def test_noise_plan_and_counter():
+    """The docs cell's prefill shapes spread their noise (gate/up: 32 rows
+    on each of 8 k-steps; down: 16 rows on 16 of 25); one k-step draws at
+    the finish; weight noise records no output-noise plan. Each traced
+    kernel counts once under its plan."""
+    assert noise_plan(512, 4096, 12800, DEFAULT_BLOCK) == (32, 8)
+    assert noise_plan(512, 12800, 4096, DEFAULT_BLOCK) == (16, 16)
+    assert noise_plan(512, 512, 4096, DEFAULT_BLOCK) == "finish"
+    assert noise_plan(512, 300, 4096, DEFAULT_BLOCK) == "finish"
+    assert noise_plan(4, 4096, 4096, DEFAULT_BLOCK) == (4, 1)  # c never above bm
+    assert noise_plan(512, 4096, 12800, DEFAULT_BLOCK, "weight") is None
+
+    before = collections.Counter(NOISE_PLANS)
+    _trace_kernel(512, 4096, 12800)
+    _trace_kernel(512, 4096, 12800)
+    _trace_kernel(512, 12800, 4096)
+    _trace_kernel(512, 512, 4096)
+    _trace_kernel(512, 4096, 12800, noise_kind="weight")
+    assert NOISE_PLANS - before == {(32, 8): 2, (16, 16): 1, "finish": 1}
