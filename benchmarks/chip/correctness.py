@@ -8,6 +8,8 @@ own noise key and tier. For every served token the reference gives its
 logits at that position; the number compared is the widest gap, over every
 sampled token, by which the served token's logit lies below the reference's
 best (greedy decoding serves the best, so a faithful run reads rounding).
+A cell of several chips runs the reference on the program's mesh, its
+weights drawn in their shards there, so no chip holds the whole model.
 The control is the same reference with the operands of every matrix product
 rounded to a lower precision: at each of the same positions it puts its own
 best token first, and its gap is read the same way.
@@ -67,15 +69,17 @@ def _gap_fn():
 
 
 def compare(ref, cfg: dict, mix: dict, seed: int, picked: list, *, platform: str,
-            control: Optional[str] = None) -> Dict[str, float]:
-    """Run the reference over ``picked`` tracks; returns the readings."""
+            control: Optional[str] = None, mesh=None) -> Dict[str, float]:
+    """Run the reference over ``picked`` tracks (on ``mesh``, where the
+    program ran on one); returns the readings."""
     import jax
     import jax.numpy as jnp
 
     t0 = time.perf_counter()
     d = ref.dims(cfg)
     a = cfg["analog"]
-    weights = ref.init_weights(harness.weight_key(seed), cfg)
+    weights = ref.init_weights(harness.weight_key(seed), cfg, mesh=mesh)
+    chips = 1 if mesh is None else mesh.size
     jax.block_until_ready(weights)
     ladder = mix["engine"]["seq_buckets"]
     by_tier: Dict[str, List] = {}
@@ -86,8 +90,8 @@ def compare(ref, cfg: dict, mix: dict, seed: int, picked: list, *, platform: str
         reps = harness.tier_repeats(mix["tiers"][tier], d["n_layers"])
         m_pre = traffic_lib.bucket(max(t.req.prompt.size for t in group), ladder)
         k_min = min(min(k, n) for _, k, n in flops.matmul_shapes(d))
-        streams = (ref.stream_for(platform, a["backend"], m_pre, k_min, k_min),
-                   ref.stream_for(platform, a["backend"], 1, k_min, k_min))
+        streams = (ref.stream_for(platform, a["backend"], m_pre, k_min, k_min, chips),
+                   ref.stream_for(platform, a["backend"], 1, k_min, k_min, chips))
         # one fixed length per mix, so every run reuses the compiled reference
         T = -(-(max(ladder) + mix["engine"]["max_gen"]) // 128) * 128
         for i in range(0, len(group), BATCH):

@@ -6,6 +6,12 @@ engine's counters (``stats``, ``cache_stats()``) and its pool records (the
 tokens each slot has emitted) to stamp token times on the host clock right
 after each ``pump_step`` returns: the engine reads every token back to the
 host inside ``pump_step``, so a stamp comes after the device finished.
+
+A cell of more than one chip runs on the first ``chips`` devices, as the
+program's tensor-parallel mesh (``repro.launch.mesh``: "data" x "model", 1 x
+chips) that is handed to the engine. Its weights are drawn on that mesh in
+the shards the program's tensor-parallel rules give them
+(``sharding.DEFAULT_RULES``), so no chip ever holds them whole at set-up.
 """
 from __future__ import annotations
 
@@ -90,12 +96,33 @@ def tier_repeats(spec: dict, n_layers: int) -> List[int]:
     return reps
 
 
-class Cell:
-    """One configuration served under one traffic mix."""
+def tp_mesh(devices):
+    """The program's tensor-parallel mesh over exactly ``devices``, the first
+    of those JAX sees."""
+    from repro.launch.mesh import make_mesh_for_devices
 
-    def __init__(self, cfg: dict, mix: dict, ref, seed: int):
+    mesh = make_mesh_for_devices(len(devices), model_parallel=len(devices))
+    if set(mesh.devices.flat) != set(devices):
+        raise ValueError(f"the mesh holds {mesh.devices.flat}, the cell {devices}")
+    return mesh
+
+
+def weight_shardings(mcfg, mesh):
+    """Each weight's layout on ``mesh`` under the tensor-parallel rules."""
+    from repro.models import lm, sharding
+
+    return sharding.tree_shardings(lm.param_axes(mcfg), lm.param_specs(mcfg), mesh,
+                                   sharding.DEFAULT_RULES)
+
+
+class Cell:
+    """One configuration served under one traffic mix, on ``devices``."""
+
+    def __init__(self, cfg: dict, mix: dict, ref, seed: int, devices):
         self.cfg, self.mix, self.ref, self.seed = cfg, mix, ref, seed
+        self.devices = list(devices)
         self.dims = ref.dims(cfg)
+        self.mesh = None
         self.engine = None
         self.params = None
         self.tier_ids: Dict[str, object] = {}
@@ -116,8 +143,14 @@ class Cell:
         if a["noise"] != "shot":
             raise ValueError(f"unsupported noise {a['noise']!r}")
         mcfg = ModelConfig(**self.ref.program_kwargs(self.cfg))
+        if len(self.devices) > 1:
+            self.mesh = tp_mesh(self.devices)
+            init = jax.jit(lm.init_params, static_argnums=1,
+                           out_shardings=weight_shardings(mcfg, self.mesh))
+        else:
+            init = jax.jit(lm.init_params, static_argnums=1)
         t0 = time.perf_counter()
-        self.params = jax.jit(lm.init_params, static_argnums=1)(weight_key(self.seed), mcfg)
+        self.params = init(weight_key(self.seed), mcfg)
         jax.block_until_ready(self.params)
         self.facts["weight_init_s"] = time.perf_counter() - t0
         self.engine = ServingEngine(
@@ -127,6 +160,7 @@ class Cell:
             max_gen=int(e["max_gen"]), max_batch=max(e["batch_buckets"]),
             batch_buckets=tuple(e["batch_buckets"]), seq_buckets=tuple(e["seq_buckets"]),
             max_wait=float(e["max_wait"]), continuous=True, pool_slots=int(e["pool_slots"]),
+            mesh=self.mesh,
         )
         for name, spec in self.mix["tiers"].items():
             if "k" in spec:
@@ -166,9 +200,9 @@ class Cell:
 
         ``on_pump(now, t_open)`` is called before each pump (the traced run
         starts and stops its trace there). Returns a dict of what the host
-        saw: the tracks, the window's bounds, generator lateness, the pumps
-        and the prefill dispatches observed (pump start, batch bucket, seq
-        bucket, real prompt tokens)."""
+        saw: the tracks, the window's open and close and the drain's end,
+        generator lateness, the pumps and the prefill dispatches observed
+        (pump start, batch bucket, seq bucket, real prompt tokens)."""
         eng, mix = self.engine, self.mix
         e = mix["engine"]
         closed = mix["loop"] == "closed"
@@ -268,8 +302,9 @@ class Cell:
             results = eng.pump_step()
             t1 = time.perf_counter()
             stamp(results, t0, t1)
-        return dict(tracks=order, t_open=t_open, t_close=t_close, lateness=lateness,
-                    prefills=prefills, pumps=pumps, ran_dry=ran_dry,
+        t_end = time.perf_counter()
+        return dict(tracks=order, t_open=t_open, t_close=t_close, t_end=t_end,
+                    lateness=lateness, prefills=prefills, pumps=pumps, ran_dry=ran_dry,
                     drained=eng.n_in_flight == 0)
 
     def release(self) -> None:

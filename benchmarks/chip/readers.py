@@ -4,7 +4,9 @@ A reader gets ``ctx``: the reduced trace (``trace_reduce.load``), its
 ``summary``, the traced window's host-clock bounds ``t0``/``t1`` and the
 offset from the host clock to the trace's (``offset_ns``), the engine
 counters at both bounds, what the harness saw (``drive``), the model's
-sizes (``dims``) and the chip's peaks (``peak``).
+sizes (``dims``), one chip's peaks (``peak``), and the cell's ``chips`` and
+their ``device_ids`` (the trace holds those devices' planes only; a rate of
+the whole cell divides by ``chips`` times a chip's peak).
 """
 from __future__ import annotations
 

@@ -25,7 +25,14 @@ settings it states:
   for calls of at least 128 rows, columns and depth on a TPU, and
   ``jax.random.normal`` over the output at ``K * E`` otherwise. A request's
   key is folded with the decode position (decode only), then the layer, then
-  a 32-bit BLAKE2s hash of the site name.
+  a 32-bit BLAKE2s hash of the site name. On a mesh of more than one chip
+  every product draws the counter stream (``stream_for``).
+* On a mesh (``init_weights(..., mesh=)``), each leaf is drawn in the shards
+  a tensor-parallel deployment holds: heads, MLP columns and the vocabulary
+  split over the mesh's ``model`` axis where it divides them, the rest whole
+  on every chip. The values do not depend on the layout (JAX draws with
+  ``jax_threefry_partitionable``), and the functions below run over such
+  weights as over one chip's.
 
 ``logits`` runs prompt plus served tokens teacher-forced and returns the
 logits at every position; ``control_dtype`` rounds the operands of every
@@ -50,6 +57,8 @@ _ROT_A = (13, 15, 26, 6)
 _ROT_B = (17, 29, 16, 24)
 #: smallest rows, depth and columns at which a TPU runs the counter stream
 MIN_COUNTER_DIM = 128
+#: mesh axis over which a tensor-parallel deployment splits a layer
+MODEL_AXIS = "model"
 
 
 # --------------------------------------------------------------------------
@@ -99,8 +108,11 @@ def padded_vocab(vocab: int) -> int:
 
 
 class _Leaf:
-    def __init__(self, shape, scale):
-        self.shape, self.scale = tuple(shape), float(scale)
+    """A weight's shape, its initial scale (0: zeros), and the dim that a
+    tensor-parallel deployment splits over ``MODEL_AXIS`` (None: whole)."""
+
+    def __init__(self, shape, scale, split=None):
+        self.shape, self.scale, self.split = tuple(shape), float(scale), split
 
 
 def layout(c: dict) -> dict:
@@ -112,24 +124,24 @@ def layout(c: dict) -> dict:
         "ln1_0": _Leaf((L, dm), 0.0),
         "ln2_0": _Leaf((L, dm), 0.0),
         "attn0": {
-            "wq": _Leaf((L, dm, qd), s), "wk": _Leaf((L, dm, kd), s),
-            "wv": _Leaf((L, dm, kd), s), "wo": _Leaf((L, qd, dm), qd ** -0.5),
+            "wq": _Leaf((L, dm, qd), s, 2), "wk": _Leaf((L, dm, kd), s, 2),
+            "wv": _Leaf((L, dm, kd), s, 2), "wo": _Leaf((L, qd, dm), qd ** -0.5, 1),
         },
     }
     if d["mlp"] == "swiglu":
         blocks["mlp0"] = {
-            "w_gate": _Leaf((L, dm, ff), s), "w_up": _Leaf((L, dm, ff), s),
-            "w_down": _Leaf((L, ff, dm), ff ** -0.5),
+            "w_gate": _Leaf((L, dm, ff), s, 2), "w_up": _Leaf((L, dm, ff), s, 2),
+            "w_down": _Leaf((L, ff, dm), ff ** -0.5, 1),
         }
     else:
         blocks["mlp0"] = {
-            "w_in": _Leaf((L, dm, ff), s), "b_in": _Leaf((L, ff), 0.0),
-            "w_down": _Leaf((L, ff, dm), ff ** -0.5), "b_out": _Leaf((L, dm), 0.0),
+            "w_in": _Leaf((L, dm, ff), s, 2), "b_in": _Leaf((L, ff), 0.0, 1),
+            "w_down": _Leaf((L, ff, dm), ff ** -0.5, 1), "b_out": _Leaf((L, dm), 0.0),
         }
     vp = padded_vocab(d["vocab"])
-    tree = {"blocks": blocks, "embed": _Leaf((vp, dm), 0.02), "final_ln": _Leaf((dm,), 0.0)}
+    tree = {"blocks": blocks, "embed": _Leaf((vp, dm), 0.02, 0), "final_ln": _Leaf((dm,), 0.0)}
     if not c["tie_word_embeddings"]:
-        tree["lm_head"] = _Leaf((dm, vp), s)
+        tree["lm_head"] = _Leaf((dm, vp), s, 1)
     return tree
 
 
@@ -138,16 +150,36 @@ def _draw(key, shape, scale):
     return (jax.random.normal(key, shape, jnp.float32) * scale).astype(jnp.bfloat16)
 
 
-def init_weights(key, c: dict):
-    """bfloat16 weights from a raw PRNG key, one leaf at a time on the device."""
+@functools.lru_cache(maxsize=None)
+def _draw_on(sharding):
+    """``_draw`` with its output laid out by ``sharding``: each chip draws
+    only its own shard."""
+    return jax.jit(_draw.__wrapped__, static_argnums=(1, 2), out_shardings=sharding)
+
+
+def leaf_sharding(leaf: _Leaf, mesh):
+    """The leaf's layout on ``mesh``: its ``split`` dim over ``MODEL_AXIS``
+    where the axis divides it, whole on every chip otherwise."""
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    spec = [None] * len(leaf.shape)
+    if leaf.split is not None and leaf.shape[leaf.split] % mesh.shape[MODEL_AXIS] == 0:
+        spec[leaf.split] = MODEL_AXIS
+    return NamedSharding(mesh, PartitionSpec(*spec))
+
+
+def init_weights(key, c: dict, mesh=None):
+    """bfloat16 weights from a raw PRNG key, one leaf at a time on the device;
+    with ``mesh``, each leaf drawn in its shards (``leaf_sharding``)."""
     leaves, treedef = jax.tree.flatten(layout(c), is_leaf=lambda x: isinstance(x, _Leaf))
     keys = jax.random.split(key, len(leaves))
     out = []
     for leaf, k in zip(leaves, keys):
+        sh = None if mesh is None else leaf_sharding(leaf, mesh)
         if leaf.scale == 0.0:
-            out.append(jnp.zeros(leaf.shape, jnp.bfloat16))
+            out.append(jnp.zeros(leaf.shape, jnp.bfloat16, device=sh))
         else:
-            out.append(_draw(k, leaf.shape, leaf.scale))
+            out.append((_draw if sh is None else _draw_on(sh))(k, leaf.shape, leaf.scale))
     return treedef.unflatten(out)
 
 
@@ -155,13 +187,19 @@ def site_hash(site: str) -> int:
     return int.from_bytes(hashlib.blake2s(site.encode(), digest_size=4).digest(), "little")
 
 
-def stream_for(platform: str, backend: str, m: int, k: int, n: int) -> str:
+def stream_for(platform: str, backend: str, m: int, k: int, n: int, chips: int = 1) -> str:
     """Noise stream of one matrix product of ``m`` rows, as the served path
-    states its dispatch."""
+    states its dispatch, on ``chips`` chips."""
     if backend in ("pallas", "tile"):
         return COUNTER
     if backend == "jnp":
         return RANDOM
+    # "auto" on a tensor-parallel mesh: every product too small for the
+    # fused kernel (m = 1 decode among them) goes to the tile oracle, which
+    # draws the counter stream and can be split by columns; the random
+    # stream cannot, so the served path never draws it there
+    if chips > 1:
+        return COUNTER
     if platform == "tpu" and min(m, k, n) >= MIN_COUNTER_DIM:
         return COUNTER
     return RANDOM

@@ -11,12 +11,14 @@ checkout. It names a configuration (``configs/<name>.json``, whose
 1. keeps JAX's persistent compilation cache at ``<checkout>/.jax_cache``;
 2. checks that JAX sees a TPU with as many chips as the cell asks for, and
    otherwise exits non-zero and prints no result;
-3. makes the weights on the device from the seed, under ``jit``;
+3. makes the weights on the device from the seed, under ``jit``; a cell of
+   ``chips`` > 1 runs on the first ``chips`` devices as one tensor-parallel
+   mesh, its weights drawn in their shards (``harness.py``);
 4. warms every executable the mix's bucket ladder can reach, and no other;
 5. serves the mix for ``--seconds`` through the engine's ``submit`` and
    ``pump_step``, then drains what is in flight;
 6. frees the program and compares a sample of what it served with the plain
-   reference (``correctness.py``);
+   reference (``correctness.py``), on the cell's mesh where it has one;
 7. prints set-up facts and the compared numbers on standard error, and one
    JSON line as the last line of standard output.
 
@@ -101,8 +103,14 @@ def percentile(values, q: float) -> float:
     return float(np.percentile(np.asarray(values, np.float64), q))
 
 
-def end_to_end(drive: dict, seconds: float) -> dict:
-    """Every end-to-end metric the harness can read from one window."""
+def end_to_end(drive: dict) -> dict:
+    """Every end-to-end metric the harness can read from one window.
+
+    ``out_tok_s`` is every token of every request sent in the window, over
+    the window's open to the end of the drain that follows its close: all
+    the work that was sent and all the time it took. Counted only up to the
+    close, the rate moved in steps of a whole pump's tokens, by where the
+    close fell in a pump."""
     ttft, itl = [], []
     out_tokens = 0
     for t in drive["tracks"]:
@@ -111,8 +119,8 @@ def end_to_end(drive: dict, seconds: float) -> dict:
             itl.extend((b - a) * 1e3 for a, b in zip(t.times, t.times[1:]))
         else:
             ttft.append(float("inf"))  # missing: failed, or never served
-        out_tokens += sum(1 for x in t.times if x <= drive["t_close"])
-    out = dict(out_tok_s=out_tokens / seconds)
+        out_tokens += len(t.times)
+    out = dict(out_tok_s=out_tokens / (drive["t_end"] - drive["t_open"]))
     if ttft:
         out["ttft_p95_ms"] = percentile(ttft, 95)
     if itl:
@@ -178,9 +186,11 @@ def run(args, spec, devices, counter) -> int:
 
     mix = spec["mix"]
     cfg, ref = spec["cfg"], spec["ref"]
+    chips = int(spec["cell"]["chips"])
+    devices = devices[:chips]
     dev = devices[0]
     peak = peaks_lib.peaks(dev.device_kind)
-    cell = harness.Cell(cfg, mix, ref, args.seed)
+    cell = harness.Cell(cfg, mix, ref, args.seed, devices)
     requests = traffic_lib.generate(mix, args.seed, args.seconds, ref.dims(cfg)["vocab"])
     cell.build()
     cell.warm()
@@ -197,9 +207,8 @@ def run(args, spec, devices, counter) -> int:
     drive = cell.drive(requests, args.seconds, on_pump=tracer.on_pump if tracer else None)
     c1 = cell.counters()
     at_window = counter.snapshot()
-    stats = dev.memory_stats() or {}
-    mem_peak = stats.get("peak_bytes_in_use")
-    e2e = end_to_end(drive, args.seconds)
+    mem_peak, mem_by_chip = memory_peaks(devices)
+    e2e = end_to_end(drive)
 
     lat = [x * 1e3 for x in drive["lateness"]]
     facts = dict(
@@ -209,7 +218,7 @@ def run(args, spec, devices, counter) -> int:
         persistent_cache_hits=at_setup["cache_hits"],
         persistent_cache_misses=at_setup["cache_misses"],
         compiles_in_window=at_window["compiles"] - at_setup["compiles"],
-        memory_peak_bytes=mem_peak,
+        memory_peak_bytes=mem_peak, memory_peak_bytes_by_chip=mem_by_chip,
         generator_late_ms_p50=percentile(lat, 50) if lat else None,
         generator_late_ms_max=max(lat) if lat else None,
         requests_sent=len(drive["tracks"]), drained=drive["drained"],
@@ -225,10 +234,11 @@ def run(args, spec, devices, counter) -> int:
 
     per_layer = {}
     breakdown = None
-    device_info = dict(platform=dev.platform, kind=dev.device_kind, count=len(devices),
-                       memory_peak_bytes=mem_peak)
+    device_info = dict(platform=dev.platform, kind=dev.device_kind, count=chips,
+                       memory_peak_bytes=mem_peak, memory_peak_bytes_by_chip=mem_by_chip)
     if tracer is not None:
         summ, ctx = tracer.reduce(cell, drive, spec, peak)
+        print(f"fact trace_planes: {sorted(ctx['trace']['devices'])}", file=sys.stderr)
         device_info.update(busy_s=summ["busy_s"], window_s=summ["window_s"])
         breakdown = dict(device_ops=summ["device_ops"], idle_gaps=summ["idle_gaps"])
         for m in spec["per_layer"]:
@@ -249,7 +259,8 @@ def run(args, spec, devices, counter) -> int:
     del drive
     gc.collect()
     readings = correctness.compare(ref, cfg, mix, args.seed, picked,
-                                   platform=dev.platform, control=args.control)
+                                   platform=dev.platform, control=args.control,
+                                   mesh=cell.mesh)
     for k, v in readings.items():
         print(f"check {k}: {v}", file=sys.stderr)
     limit = spec["limits"].get("logit_gap_max", {}).get("limit")
@@ -275,6 +286,14 @@ def run(args, spec, devices, counter) -> int:
     sys.stderr.flush()
     print(json.dumps(result), flush=True)
     return 0
+
+
+def memory_peaks(devices):
+    """(the fullest chip's peak bytes, each chip's peak): over the chips that
+    report one; None where none does (the CPU reports no memory stats)."""
+    by_chip = [(d.memory_stats() or {}).get("peak_bytes_in_use") for d in devices]
+    known = [b for b in by_chip if b is not None]
+    return (max(known) if known else None), by_chip
 
 
 def backlog(drive: dict) -> dict:
@@ -330,7 +349,8 @@ class Tracer:
 
         if self.state == "on":
             self.on_pump(float("inf"), 0.0)
-        tr = trace_reduce.load(trace_reduce.find_xplane(self.dir))
+        ids = [d.id for d in cell.devices]
+        tr = trace_reduce.load(trace_reduce.find_xplane(self.dir), ids)
         if self.keep:
             shutil.copytree(self.dir, self.keep, dirs_exist_ok=True)
         shutil.rmtree(self.dir, ignore_errors=True)
@@ -338,6 +358,7 @@ class Tracer:
         ctx = dict(trace=tr, summary=summ, t0=self.t0, t1=self.t1, drive=drive,
                    counters=(self.c0, self.c1),
                    dims=spec["ref"].dims(spec["cfg"]), peak=peak, mix=cell.mix,
+                   chips=len(ids), device_ids=ids,
                    offset_ns=tr["window"][0] - int(self.t0 * 1e9))
         return summ, ctx
 

@@ -136,3 +136,15 @@ def test_broken_timed_path_is_not_correct(spec, capsys, monkeypatch, how):
     res, _ = _run(spec, capsys, seed=11)
     assert not res["correct"]
     assert res["compared"]["logit_gap_max"]["value"] > LIMIT
+
+
+@pytest.mark.parametrize("close", [4.9, 5.1])  # either side of a token at 5.0
+def test_out_tok_s_takes_the_drained_window(close):
+    """The rate counts every token of every request sent, over the window's
+    open to the drain's end: where the close falls inside a pump does not
+    step it."""
+    req = traffic.Request(0, None, 4, "k1", None, 0.0)
+    tracks = [harness.Track(req, uid, due=0.0, times=[2.0 * uid + 1.0 + i for i in range(4)])
+              for uid in range(3)]  # the last token back at 8.0 s
+    drive = dict(tracks=tracks, t_open=0.0, t_close=close, t_end=8.5)
+    assert run.end_to_end(drive)["out_tok_s"] == pytest.approx(12 / 8.5)

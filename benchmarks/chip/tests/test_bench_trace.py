@@ -70,3 +70,47 @@ def test_recorded_tpu_trace():
     assert idle == {"wait_arrival": 16_355_713, "pump_step": 2_086_961,
                     "submit": 953_449, "unattributed": 39_069}
     assert sum(idle.values()) == (we - ws) - 362_327
+
+
+def _with_plane_of_device_1(tmp_path, shift_ns):
+    """The fixture with one more plane: a copy of ``/device:TPU:0`` named
+    ``/device:TPU:1``, every line of it ``shift_ns`` later. XSpace field 1
+    holds each XPlane, whose field 2 is its name and field 3 its lines; an
+    XLine's field 3 is the time in ns its events are offset from, which the
+    fixture's lines leave at 0 (absent). Protobuf merges a message appended
+    to another, so a field appended sets it, and the copy is the file with
+    the new plane appended."""
+    import make_engine_trace_fixture as pb
+
+    def shifted(line):
+        return line + pb._varint_bytes(3 << 3) + pb._varint_bytes(shift_ns)
+
+    with open(FIXTURE, "rb") as f:
+        space = f.read()
+    plane = next(v for f, v, _ in pb._fields(space)
+                 if f == 1 and any(g == 2 and w == b"/device:TPU:0" for g, w, _ in pb._fields(v)))
+    copy = b"".join(pb._field(2, b"/device:TPU:1") if f == 2 else
+                    pb._field(3, shifted(v)) if f == 3 else raw
+                    for f, v, raw in pb._fields(plane))
+    path = tmp_path / "two.xplane.pb"
+    path.write_bytes(space + pb._field(1, copy))
+    return str(path)
+
+
+def test_only_the_cell_planes_are_read(tmp_path):
+    """A one-chip cell on a host of several chips reads its chip's plane
+    alone. The extra plane is device 0's a millisecond later: its first run
+    then lies in the window too, three runs busy where device 0 has two."""
+    path = _with_plane_of_device_1(tmp_path, 1_000_000)
+    both = tr.load(path)
+    assert sorted(both["devices"]) == ["/device:TPU:0", "/device:TPU:1"]
+    ws, we = both["window"]
+    # run 1 of the copy: 13 + 3 + 89714 + 91449 ns, now inside the window
+    assert tr.busy(both["devices"]["/device:TPU:1"]["ops"], ws, we) == 362_327 + 181_179
+    assert tr.summary(both)["busy_s"] == (362_327 + 362_327 + 181_179) / 2 / 1e9
+    # kept to device 0: every reading of test_recorded_tpu_trace, unchanged
+    alone, plain = tr.load(path, [0]), tr.load(FIXTURE)
+    assert alone == plain and list(alone["devices"]) == ["/device:TPU:0"]
+    assert tr.summary(alone) == tr.summary(plain)
+    assert tr.summary(alone)["busy_s"] == 362_327 / 1e9
+    assert list(tr.load(path, [1])["devices"]) == ["/device:TPU:1"]

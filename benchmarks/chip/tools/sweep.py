@@ -48,14 +48,14 @@ def main(argv=None) -> int:
     mix, cfg, ref = spec["mix"], spec["cfg"], spec["ref"]
     if mix["loop"] != "open":
         run.fail("the sweep is for open-loop mixes")
-    cell = harness.Cell(cfg, mix, ref, args.seed)
+    cell = harness.Cell(cfg, mix, ref, args.seed, devices[:int(spec["cell"]["chips"])])
     cell.build()
     cell.warm()
     for rate in [float(x) for x in args.rates.split(",")]:
         cell.mix = dict(mix, rate_per_s=rate)
         reqs = traffic_lib.generate(cell.mix, args.seed, args.seconds, ref.dims(cfg)["vocab"])
         drive = cell.drive(reqs, args.seconds)
-        out = {"rate": rate, **run.end_to_end(drive, args.seconds), **run.backlog(drive),
+        out = {"rate": rate, **run.end_to_end(drive), **run.backlog(drive),
                **gap_shape(drive)}
         print(f"sweep {json.dumps(out)}", file=sys.stderr, flush=True)
     return 0

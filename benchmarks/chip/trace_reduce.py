@@ -5,6 +5,9 @@ tuples of (name, start_ns, duration_ns):
 
 * for each device plane (``/device:TPU:<n>``), the events of its ``XLA Ops``
   line, one per operation run on the device, named by its HLO instruction;
+  given the cell's device ids, only the planes of those devices (the
+  profiler records every chip of the host, and a chip the cell does not use
+  would dilute the busy share averaged over the planes);
 * the host spans the harness opens with ``jax.profiler.TraceAnnotation``
   (``HOST_SPANS``), from every host thread.
 
@@ -27,13 +30,14 @@ from __future__ import annotations
 
 import glob
 import os
-from typing import Dict, Iterable, List, Tuple
+from typing import Dict, Iterable, List, Optional, Tuple
 
 Event = Tuple[str, int, int]  # (name, start_ns, duration_ns)
 
 HOST_SPANS = ("submit", "pump_step", "bookkeeping", "wait_arrival")
 WINDOW_SPAN = "traced_window"
 OPS_LINE = "XLA Ops"
+DEVICE_PLANE = "/device:TPU:"
 #: instruction-name prefixes of control flow (a layer scan is a ``while``)
 CONTAINERS = ("%while", "%conditional", "%call")
 
@@ -45,14 +49,18 @@ def find_xplane(log_dir: str) -> str:
     return paths[-1]
 
 
-def load(path: str) -> dict:
-    """Device and host events of one trace, as plain tuples."""
+def load(path: str, device_ids: Optional[Iterable[int]] = None) -> dict:
+    """Device and host events of one trace, as plain tuples; with
+    ``device_ids``, only those devices' planes."""
     from jax.profiler import ProfileData
 
     pd = ProfileData.from_file(path)
+    keep = None if device_ids is None else {int(i) for i in device_ids}
     devices, host, window = {}, [], None
     for plane in pd.planes:
-        if plane.name.startswith("/device:TPU:"):
+        if plane.name.startswith(DEVICE_PLANE):
+            if keep is not None and int(plane.name[len(DEVICE_PLANE):]) not in keep:
+                continue
             ops = []
             for line in plane.lines:
                 if line.name == OPS_LINE:
@@ -145,7 +153,7 @@ def top(d: Dict[str, int], n: int = 10) -> List[list]:
 
 
 def summary(tr: dict) -> dict:
-    """Window, busy time and breakdown, averaged over the devices."""
+    """Window, busy time and breakdown, averaged over the devices kept."""
     ws, we = tr["window"]
     devs = list(tr["devices"].values())
     if not devs:
