@@ -14,10 +14,13 @@ shot-noise analog, and int8 weight-only digital -- through the continuous
   * serving the same traffic again returns the same tokens, and
   * a request served solo returns the tokens it got inside the batch.
 
-``--chips 4`` runs only the tensor-parallel path: the K=1 and K=8 requests
-of the two 512-bucket prompts are served unsharded on device 0, then on a
-1x4 ("data", "model") mesh, and the run fails unless the tokens are
-identical. Both runs pin the analog
+``--chips 4`` runs only the tensor-parallel path: the weights are drawn in
+their shards on a 1x4 ("data", "model") mesh (the tensor-parallel rules, as
+the chip benchmark draws them), the K=1 and K=8 requests of the two
+512-bucket prompts are served unsharded on device 0 from a whole copy, then
+on the mesh from the shards, and the run fails unless the tokens are
+identical, any analog dot fell back to the whole product, or the mesh
+engine holds a weight whole. Both runs pin the analog
 backend to the Pallas kernel: under "auto" a decode matmul resolves to the
 jnp path unsharded but to the tile oracle under a mesh, and those draw
 different noise streams by design (kernels/dispatch.py).
@@ -202,25 +205,21 @@ def placement(tree) -> str:
 
 def run_four_chips(cfg, seed: int) -> None:
     import jax
-    from jax.sharding import NamedSharding, PartitionSpec
 
+    from repro.core.analog import SHARDED_DOTS
     from repro.launch.mesh import make_mesh_for_devices
-    from repro.models import lm
+    from repro.models import lm, sharding
 
     mesh = make_mesh_for_devices(4, model_parallel=4)
     dev0 = jax.devices()[0]
     t0 = time.perf_counter()
     params = jax.jit(
         lm.init_params, static_argnums=1,
-        out_shardings=NamedSharding(mesh, PartitionSpec()),
+        out_shardings=sharding.tree_shardings(
+            lm.param_axes(cfg), lm.param_specs(cfg), mesh, sharding.DEFAULT_RULES),
     )(jax.random.PRNGKey(seed), cfg)
     jax.block_until_ready(params)
-    # device 0's replica of each weight, aliased (no second copy)
-    on_dev0 = jax.tree.map(
-        lambda a: next(s.data for s in a.addressable_shards if s.device == dev0),
-        params,
-    )
-    print(f"weights initialized replicated from seed {seed} in "
+    print(f"weights initialized in their shards from seed {seed} in "
           f"{time.perf_counter() - t0:.1f} s; mesh {dict(mesh.shape)} over "
           f"devices {[d.id for d in mesh.devices.flat]}")
     # the two prompts of the top seq bucket: one prefill shape per tier
@@ -229,7 +228,7 @@ def run_four_chips(cfg, seed: int) -> None:
 
     out = {}
     for name, build in (
-        ("unsharded", lambda: make_engine(on_dev0, cfg, backend="pallas")),
+        ("unsharded", lambda: make_engine(jax.device_put(params, dev0), cfg, backend="pallas")),
         ("sharded", lambda: make_engine(params, cfg, backend="pallas", mesh=mesh)),
     ):
         engine = build()
@@ -239,8 +238,18 @@ def run_four_chips(cfg, seed: int) -> None:
         print(f"{name}: served {len(out[name])} requests in "
               f"{time.perf_counter() - t0:.1f} s "
               f"({engine.cache_stats()['compile_s']:.1f} s compiling); weights "
-              f"on {placement(engine.params)}, pool caches on {placement(pools)}")
+              f"on {placement(engine.params)}, {engine.weight_bytes_per_chip / 1e9:.3f} GB "
+              f"a chip; pool caches on {placement(pools)}")
+        if engine.mesh is not None:
+            whole = [jax.tree_util.keystr(p) for p, a in
+                     jax.tree_util.tree_flatten_with_path(engine.params)[0]
+                     if a.ndim == 3 and a.sharding.is_fully_replicated]
+            check(not whole, f"the mesh engine holds {whole} whole")
         del engine, pools
+    fallbacks = {k: n for k, n in SHARDED_DOTS.items() if k[0] == "fallback"}
+    print(f"sharded dots: {sum(n for k, n in SHARDED_DOTS.items() if k[0] == 'column')} "
+          f"column-parallel, {sum(fallbacks.values())} fallbacks")
+    check(not fallbacks, f"analog dots fell back to the whole product: {fallbacks}")
     n_same = 0
     for k, a in out["unsharded"].items():
         b = out["sharded"][k]

@@ -16,6 +16,7 @@ Energies may be scalar (per-layer) or per-output-channel vectors (§V).
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import hashlib
 from typing import Optional
@@ -32,6 +33,13 @@ Array = jax.Array
 
 PER_LAYER = "per_layer"
 PER_CHANNEL = "per_channel"
+
+#: analog dots traced under a tensor-parallel mesh (trace time, like the
+#: fused kernel's ``NOISE_PLANS``): ``("column", site)`` for each
+#: column-parallel instantiation, ``("fallback", site, reason)`` for each
+#: that fell back to the whole product on every chip. ``site`` is the hook's
+#: site name (None for a caller that names none).
+SHARDED_DOTS: collections.Counter = collections.Counter()
 
 
 @jax.tree_util.register_dataclass
@@ -182,6 +190,22 @@ def _x_range(sq: SiteQuant, x: Array) -> Array:
     return (jnp.max(x) - jnp.min(x)).astype(jnp.float32)
 
 
+def _column_parallel_misfit(x, w, *, cfg, energy, sq, tp: int) -> Optional[str]:
+    """Why an analog dot cannot run column-parallel over ``tp`` chips, or
+    None where it can."""
+    if sq is not None:
+        return "calibrated quantizers"
+    if w.ndim != 2:
+        return "not a matrix"
+    if w.shape[1] % tp != 0:
+        return "columns do not divide"
+    if jnp.ndim(energy) != 0:
+        return "per-channel energy"  # its columns would need co-sharding
+    if resolve_backend(cfg, x.shape, (w.shape[0], w.shape[1] // tp)) not in ("tile", "pallas"):
+        return "backend not tiling-invariant"
+    return None
+
+
 def _maybe_sharded_analog_dot(
     x: Array,
     w: Array,
@@ -191,6 +215,7 @@ def _maybe_sharded_analog_dot(
     key: jax.Array,
     sq: Optional[SiteQuant],
     n_repeats: int,
+    site: Optional[str],
 ) -> Optional[Array]:
     """Column-parallel analog matmul through shard_map, or None to fall back.
 
@@ -203,10 +228,16 @@ def _maybe_sharded_analog_dot(
     cross-device rounding) and the gather back to replicated is pure data
     movement, so bit-identity is exact, not approximate.
 
-    Falls back (returns None) when there is no active tensor-parallel mesh,
-    when the resolved backend is not tiling-invariant ("jnp"), or when the
-    operands don't fit the column-parallel contract (calibrated quantizers,
-    per-channel energies, N not divisible by the shard count).
+    ``w`` is taken as each chip holds it: a mesh engine lays every analog
+    weight out by its columns (``sharding.serving_spec``), so a chip's shard
+    is its ``in_specs`` block and nothing is sliced. A weight that arrives
+    whole is sliced to its blocks, at the cost of reading it whole.
+
+    Returns None without a tensor-parallel mesh. Under one it falls back
+    (None, counted in ``SHARDED_DOTS`` with the reason) when the resolved
+    backend is not tiling-invariant ("jnp"), or when the operands don't fit
+    the column-parallel contract (calibrated quantizers, per-channel
+    energies, N not divisible by the shard count).
     """
     from repro.kernels.dispatch import active_mesh
 
@@ -216,14 +247,13 @@ def _maybe_sharded_analog_dot(
     tp = int(dict(mesh.shape).get(TP_AXIS, 1))
     if tp <= 1:
         return None
-    if sq is not None or w.ndim != 2 or w.shape[1] % tp != 0:
+    misfit = _column_parallel_misfit(x, w, cfg=cfg, energy=energy, sq=sq, tp=tp)
+    if misfit is not None:
+        SHARDED_DOTS["fallback", site, misfit] += 1
         return None
-    if jnp.ndim(energy) != 0:
-        return None  # per-channel energy columns would need co-sharding
+    SHARDED_DOTS["column", site] += 1
     n_local = w.shape[1] // tp
     backend = resolve_backend(cfg, x.shape, (w.shape[0], n_local))
-    if backend not in ("tile", "pallas"):
-        return None
 
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.kernels import ops as kernel_ops
@@ -276,6 +306,7 @@ def analog_dot(
     sq: Optional[SiteQuant] = None,
     precision=None,
     n_repeats: int = 1,
+    site: Optional[str] = None,
 ) -> Array:
     """Noisy (or digital) matmul ``(..., K) @ (K, M) -> (..., M)``.
 
@@ -286,6 +317,7 @@ def analog_dot(
     averaged in-register inside the fused kernel (one matmul pass, one x/w
     HBM read); on the jnp path the statistically identical single draw at
     ``K * energy`` is used. Total energy spent is ``K * energy`` either way.
+    ``site`` names the call in ``SHARDED_DOTS`` only.
     """
     if x.shape[-1] != w.shape[0]:
         raise ValueError(f"contract mismatch {x.shape} @ {w.shape}")
@@ -296,10 +328,19 @@ def analog_dot(
         # run the matmul column-sharded through shard_map — checked before
         # the stacked-key vmap so ONE shard_map wraps the whole batch.
         y = _maybe_sharded_analog_dot(
-            x, w, cfg=cfg, energy=energy, key=key, sq=sq, n_repeats=n_repeats
+            x, w, cfg=cfg, energy=energy, key=key, sq=sq, n_repeats=n_repeats,
+            site=site,
         )
         if y is not None:
             return y
+    return _local_analog_dot(
+        x, w, cfg=cfg, energy=energy, key=key, sq=sq, precision=precision,
+        n_repeats=n_repeats,
+    )
+
+
+def _local_analog_dot(x, w, *, cfg, energy, key, sq, precision, n_repeats):
+    """``analog_dot`` on the whole weight, on each device that runs it."""
     kb = key_batch(key)
     if kb is not None:
         # Stacked per-request keys: one independent noise stream per leading
@@ -311,7 +352,7 @@ def analog_dot(
                 f"stacked key batch {kb} does not match x leading dim {x.shape}"
             )
         return jax.vmap(
-            lambda xr, kr: analog_dot(
+            lambda xr, kr: _local_analog_dot(
                 xr, w, cfg=cfg, energy=energy, key=kr, sq=sq,
                 precision=precision, n_repeats=n_repeats,
             )

@@ -93,7 +93,9 @@ class AnalogHook(MatmulHook):
     def __call__(self, site: str, x: Array, w: Array) -> Array:
         e = self._site_energy(site)
         k = site_key(self.key, site)
-        y = analog_dot(x, w, cfg=self.cfg, energy=e, key=k, n_repeats=self.n_repeats)
+        y = analog_dot(
+            x, w, cfg=self.cfg, energy=e, key=k, n_repeats=self.n_repeats, site=site
+        )
         return y.astype(x.dtype)
 
     def batched(self, site: str, x: Array, w: Array) -> Array:
@@ -106,7 +108,8 @@ class AnalogHook(MatmulHook):
 
         def one(xe, we, ee, ke):
             return analog_dot(
-                xe, we, cfg=self.cfg, energy=ee, key=ke, n_repeats=self.n_repeats
+                xe, we, cfg=self.cfg, energy=ee, key=ke, n_repeats=self.n_repeats,
+                site=site,
             )
 
         y = jax.vmap(one)(x, w, e, keys)

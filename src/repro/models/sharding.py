@@ -70,13 +70,14 @@ DP_RULES = {
     "zero": ("pod", "data", "model"),
 }
 
-#: serving: every logical axis replicated. The serving engine keeps all
-#: jit-boundary arrays (params, decode caches, tokens, keys) replicated so
-#: AOT executables survive mesh resize, and tensor parallelism lives ONLY
-#: inside analog_dot's shard_map (column-parallel matmul shards whose
-#: counter-based noise is salted on global tile coordinates). Under these
-#: rules the model code's constrain() calls resolve to replication, so the
-#: decode cache is never sequence-sharded out from under the pools.
+#: serving activations: every logical axis replicated. The serving engine
+#: lowers its executables under these rules, so the model code's
+#: constrain() calls resolve to replication: batch inputs, decode caches and
+#: the residual stream stay whole on every chip, and the decode cache is
+#: never sequence-sharded out from under the pools. The weights are laid out
+#: by ``serving_spec`` instead: tensor parallelism lives in analog_dot's
+#: shard_map (column-parallel matmul shards whose counter-based noise is
+#: salted on global tile coordinates).
 SERVING_RULES = {k: None for k in DEFAULT_RULES}
 
 PROFILES = {"tp": DEFAULT_RULES, "dp": DP_RULES, "serving": SERVING_RULES}
@@ -221,6 +222,40 @@ def tree_shardings(axes_tree, shapes_tree=None, mesh: Optional[Mesh] = None, rul
         axes_tree,
         shapes_tree,
         is_leaf=is_leaf,
+    )
+
+
+def serving_spec(names: Sequence[Optional[str]], shape: Sequence[int], mesh) -> P:
+    """A weight's layout for tensor-parallel serving: ``DEFAULT_RULES``'
+    spec, except that a matrix of the layer stack (logical axes ``("layers",
+    ..., rows, columns)``) is split by its columns and never by its
+    contracting rows. The analog dot is column-parallel only: each chip
+    computes whole output columns, with no sum across chips, which keeps a
+    mesh engine's tokens bit-identical to one device's. A row split that the
+    columns cannot take (they do not divide) leaves the matrix whole.
+    Embedding, head and norm scales keep ``DEFAULT_RULES``' layout."""
+    out = list(spec(names, DEFAULT_RULES, mesh, shape=shape))
+    out += [None] * (len(shape) - len(out))
+    if len(shape) >= 3 and names[0] == "layers" and out[-2] is not None:
+        axes = out[-2] if isinstance(out[-2], tuple) else (out[-2],)
+        sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
+        n = 1
+        for a in axes:
+            n *= sizes[a]
+        out[-2] = None
+        if out[-1] is None and shape[-1] % n == 0:
+            out[-1] = axes if len(axes) > 1 else axes[0]
+    return P(*out)
+
+
+def serving_shardings(axes_tree, shapes_tree, mesh: Mesh):
+    """``serving_spec`` over a tree of logical-axis tuples and the matching
+    shapes (arrays or ShapeDtypeStructs), as NamedShardings on ``mesh``."""
+    return jax.tree.map(
+        lambda names, a: NamedSharding(mesh, serving_spec(names, a.shape, mesh)),
+        axes_tree,
+        shapes_tree,
+        is_leaf=lambda x: isinstance(x, tuple),
     )
 
 

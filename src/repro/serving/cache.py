@@ -24,11 +24,12 @@ from typing import Any, Callable, Hashable, Optional
 from repro.serving import trace
 
 
-def mesh_fingerprint(mesh) -> tuple:
+def mesh_fingerprint(mesh, layout: tuple = ()) -> tuple:
     """Hashable identity of a device mesh for AOT cache keys.
 
     Everything that changes a lowered program's device assignment — axis
-    names, axis sizes, and the concrete device ordering — and nothing else.
+    names, axis sizes, the concrete device ordering, and the weights'
+    ``layout`` on the mesh (their PartitionSpecs) — and nothing else.
     ``()`` for no mesh, so unmeshed engines keep their exact legacy keys
     (appending an empty tuple is the identity). Two meshes with equal
     fingerprints produce interchangeable executables, which is what lets a
@@ -40,7 +41,21 @@ def mesh_fingerprint(mesh) -> tuple:
         tuple(mesh.axis_names),
         tuple(mesh.devices.shape),
         tuple(int(d.id) for d in mesh.devices.flat),
+        hash(tuple(layout)),
     )
+
+
+def spec_tree(tree, *, shardings: bool = False):
+    """ShapeDtypeStructs of a tree of arrays: the AOT argument specs of a
+    weight tree. With ``shardings``, each spec carries its leaf's sharding,
+    so an executable takes every weight as it lies on the mesh."""
+    import jax
+
+    if shardings:
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=a.sharding), tree
+        )
+    return jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree)
 
 
 class ExecutableBuildError(RuntimeError):

@@ -92,6 +92,7 @@ from repro.serving.cache import (
     ExecutableBuildError,
     ExecutableCache,
     mesh_fingerprint,
+    spec_tree,
 )
 from repro.serving.faults import (
     BoundedLog,
@@ -142,6 +143,14 @@ class Failed(RequestFailure):
 
 #: what poll()/flush() map a uid to: a token row, or a structured failure
 RequestResult = Union[np.ndarray, RequestFailure]
+
+
+def _bytes_a_chip(leaves, shardings) -> int:
+    """Bytes one chip holds of ``leaves`` laid out by ``shardings``."""
+    return sum(
+        int(np.prod(sh.shard_shape(a.shape))) * a.dtype.itemsize
+        for a, sh in zip(leaves, shardings)
+    )
 
 
 class ServingEngine:
@@ -278,9 +287,7 @@ class ServingEngine:
         self._mesh = None
         self._mesh_key: tuple = ()
         self._base_key = raw_key(jax.random.PRNGKey(seed))
-        self._param_specs = jax.tree.map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), params
-        )
+        self._param_specs = spec_tree(params)
         self._uid = 0
         self._clock: Optional[str] = None  # "real" | "virtual", set on first use
         self._traces = 0  # incremented at trace time inside the step fns
@@ -827,16 +834,23 @@ class ServingEngine:
     def attach_mesh(self, mesh) -> None:
         """Attach (or resize to) a device mesh for tensor-parallel serving.
 
-        All jit-boundary arrays — params, decode-pool caches, batch inputs —
-        stay *replicated* across the mesh (``SERVING_RULES``); tensor
-        parallelism lives entirely inside ``analog_dot``'s shard_map, whose
-        column shards salt their counter-based noise on global tile
-        coordinates, so a mesh engine's tokens are bit-identical to the
-        single-device oracle. Because replication is mesh-shape-agnostic,
-        executables survive *as lowered programs* across resize — but their
-        device assignment does not, so cache keys carry the mesh fingerprint:
-        a resize compiles fresh entries once, then serves at a 100% hit rate
-        again (and a resize back to a previous mesh re-hits its warm entries).
+        The weights stay sharded: each leaf is laid out by
+        ``sharding.serving_spec`` — every analog weight of the layer stack
+        split by its columns over the mesh's "model" axis, the embedding,
+        head and norm scales as ``DEFAULT_RULES`` gives them. A leaf that
+        already lies so (drawn in its shards, as ``tree_shardings(...,
+        DEFAULT_RULES)`` draws all but ``wo`` and ``w_down``) is kept as
+        given; any other is re-laid, one leaf at a time, so the move holds at
+        most one leaf's old and new shards at once. The AOT argument specs
+        carry each leaf's sharding, and ``analog_dot``'s shard_map takes
+        each chip's column shard as it lies, salting its counter-based noise
+        on global tile coordinates, so a mesh engine's tokens are
+        bit-identical to the single-device oracle. Everything else at the
+        jit boundary — decode-pool caches, batch inputs, outputs — stays
+        *replicated* (``SERVING_RULES``). Cache keys carry the mesh
+        fingerprint and the weights' layout: a resize compiles fresh entries
+        once, then serves at a 100% hit rate again (and a resize back to a
+        previous mesh re-hits its warm entries).
 
         Resizing requires a drained engine (no queued or pooled requests):
         live decode state is pinned to the old mesh's devices. Pools are
@@ -851,12 +865,50 @@ class ServingEngine:
                 "current devices); drain with flush() first"
             )
         self._mesh = mesh
-        self._mesh_key = mesh_fingerprint(mesh)
         self._pools.clear()  # rebuilt lazily, replicated on the new mesh
-        self.params = self._replicate(self.params)
-        self._param_specs = jax.tree.map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), self.params
+        if mesh is None:
+            self._param_specs = spec_tree(self.params)
+            self._mesh_key = ()
+            return
+        from repro.models import sharding as shardlib
+
+        layout = shardlib.serving_shardings(
+            lm.param_axes(self.model_cfg), self.params, mesh
         )
+        self.params = self._lay_out_weights(layout)
+        self._param_specs = jax.tree.map(
+            lambda a, sh: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh),
+            self.params, layout,
+        )
+        self._mesh_key = mesh_fingerprint(
+            mesh, [sh.spec for sh in jax.tree.leaves(layout)]
+        )
+
+    def _lay_out_weights(self, layout):
+        """The weights placed by ``layout`` (a tree of shardings): each leaf
+        that lies so already kept, each other re-laid and waited for before
+        the next (``attach_mesh``)."""
+        leaves, treedef = jax.tree.flatten(self.params)
+        targets = treedef.flatten_up_to(layout)
+        keep = [
+            isinstance(a, jax.Array) and a.sharding.is_equivalent_to(sh, a.ndim)
+            for a, sh in zip(leaves, targets)
+        ]
+        out = []
+        with trace.span(trace.ATTACH_MESH, kept=sum(keep), relaid=len(keep) - sum(keep),
+                        weight_bytes_per_chip=_bytes_a_chip(leaves, targets)):
+            for a, sh, k in zip(leaves, targets, keep):
+                if not k:
+                    a = jax.block_until_ready(jax.device_put(a, sh))
+                out.append(a)
+        return treedef.unflatten(out)
+
+    @property
+    def weight_bytes_per_chip(self) -> int:
+        """Bytes of weights each chip holds: one shard of every leaf (the
+        whole leaf where it is not split, and every leaf unmeshed)."""
+        leaves = jax.tree.leaves(self.params)
+        return _bytes_a_chip(leaves, [a.sharding for a in leaves])
 
     def _replicated_sharding(self):
         """NamedSharding(mesh, P()) when a mesh is attached, else None."""
@@ -876,9 +928,9 @@ class ServingEngine:
 
     def _mesh_ctx(self):
         """Ambient-mesh context the tier builders lower under: the attached
-        mesh with every logical axis replicated (``SERVING_RULES``), which
-        is what routes analog matmuls through the tensor-parallel shard_map
-        at trace time. A no-op context unmeshed."""
+        mesh with every activation axis replicated (``SERVING_RULES``),
+        which is what routes analog matmuls through the tensor-parallel
+        shard_map at trace time. A no-op context unmeshed."""
         if self._mesh is None:
             return contextlib.nullcontext()
         from repro.models import sharding as shardlib

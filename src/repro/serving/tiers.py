@@ -50,7 +50,7 @@ from ..core.energy import (
 from ..core.profile import PrecisionProfile
 from ..models import lm
 from ..quant.weights import quantize_params
-from .cache import aot_compile
+from .cache import aot_compile, spec_tree
 
 __all__ = [
     "AnalogProfileTier",
@@ -60,12 +60,6 @@ __all__ = [
     "TierRegistry",
     "UniformKTier",
 ]
-
-
-def _spec_tree(tree):
-    return jax.tree.map(
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree
-    )
 
 
 def _next_rung(k: int, ladder: Tuple[int, ...]) -> int:
@@ -128,14 +122,15 @@ class ExecutionTier:
             return jax.ShapeDtypeStruct(shape, dtype)
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
 
-    def _pin(self, spec_tree):
-        """Pin a tree of specs (e.g. an eval_shape'd cache) replicated."""
+    def _pin(self, specs):
+        """Pin a tree of specs (an eval_shape'd cache) replicated. The
+        weights' specs (``param_specs``) carry their own shardings."""
         sh = self.engine._replicated_sharding()
         if sh is None:
-            return spec_tree
+            return specs
         return jax.tree.map(
             lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
-            spec_tree,
+            specs,
         )
 
     # -- identity ------------------------------------------------------------
@@ -190,7 +185,7 @@ class ExecutionTier:
         with eng._mesh_ctx():
             return aot_compile(
                 prefill,
-                self._pin(self.param_specs),
+                self.param_specs,
                 self._sds((bb, sb), i32),
                 self._sds((bb,), i32),
                 eng._keys_spec(bb),
@@ -217,7 +212,7 @@ class ExecutionTier:
         with eng._mesh_ctx():
             return aot_compile(
                 decode,
-                self._pin(self.param_specs),
+                self.param_specs,
                 self._pin(cache_specs),
                 self._sds((bb, 1), i32),
                 self._sds((bb,), i32),
@@ -443,7 +438,9 @@ class Int8DigitalTier(DigitalTier):
         src = self.engine.params
         if self._qparams is None or self._src is not src:
             self._qparams = quantize_params(src)
-            self._qspecs = _spec_tree(self._qparams)
+            self._qspecs = spec_tree(
+                self._qparams, shardings=self.engine.mesh is not None
+            )
             self._src = src
         return self._qparams
 

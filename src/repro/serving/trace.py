@@ -19,7 +19,10 @@ One pump of the continuous engine (``ServingEngine._pump_once``) nests as::
       engine.decode_wait    the decoded tokens back on the host
       engine.retire         the per-slot bookkeeping
 
-and ``engine.submit`` wraps the scheduler insert of ``submit``. The spans of
+and ``engine.submit`` wraps the scheduler insert of ``submit``.
+``engine.attach_mesh`` wraps the weights' placement on a mesh, with the
+leaves ``kept`` as they lay, the leaves ``relaid`` and the
+``weight_bytes_per_chip`` in its metadata. The spans of
 one request share its uid: ``engine.submit`` carries ``uid``, and
 ``engine.prefill`` the space-separated ``uids`` of its rows: the profiler's
 metadata encoding cuts a value at a comma, and all that follows at a ``#``
@@ -43,6 +46,9 @@ COMPILE = "engine.compile"
 
 SPANS = (SUBMIT, PUMP, SCHEDULE, PREFILL, PREFILL_WAIT, INSERT, DECODE,
          DECODE_WAIT, RETIRE, COMPILE)
+
+#: set-up, outside any pump (so not among ``SPANS``)
+ATTACH_MESH = "engine.attach_mesh"
 
 
 def span(name: str, **meta) -> TraceAnnotation:

@@ -4,7 +4,14 @@ import jax
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro.models.sharding import DEFAULT_RULES, DP_RULES, spec, use_mesh, zero1_axes
+from repro.models.sharding import (
+    DEFAULT_RULES,
+    DP_RULES,
+    serving_spec,
+    spec,
+    use_mesh,
+    zero1_axes,
+)
 
 
 class FakeMesh:
@@ -75,3 +82,22 @@ def test_zero1_axes_targets_first_replicated_dim():
 
 def test_without_shape_no_filtering():
     assert spec(("vocab",), rules=DEFAULT_RULES, mesh=MESH) == P("model")
+
+
+@pytest.mark.parametrize("names, shape, want", [
+    # granite-3-8b's layer stack on the 16-wide model axis: columns stay split
+    (("layers", None, "heads"), (40, 4096, 4096), P(None, None, "model")),
+    (("layers", None, "mlp"), (40, 4096, 12800), P(None, None, "model")),
+    # wo and w_down: DEFAULT_RULES splits their contracting rows; serving
+    # moves the split to their columns
+    (("layers", "heads", None), (40, 4096, 4096), P(None, None, "model")),
+    (("layers", "mlp", None), (40, 12800, 4096), P(None, None, "model")),
+    # columns the axis does not divide: the matrix stays whole
+    (("layers", "mlp", None), (40, 12800, 4100), P(None, None, None)),
+    # embedding, head and norms keep DEFAULT_RULES' layout
+    (("vocab", None), (49168, 4096), P("model", None)),
+    ((None, "vocab"), (4096, 49168), P(None, "model")),
+    (("layers", None), (40, 4096), P(None, None)),
+])
+def test_serving_spec_splits_layer_matrices_by_columns(names, shape, want):
+    assert serving_spec(names, shape, MESH) == want
